@@ -94,8 +94,12 @@ def main():
                          "'{\"runtime.backend\": [\"serial\", \"vmap\"]}'")
     ap.add_argument("--jobs", type=int, default=1, metavar="N",
                     help="run --sweep grid points in N worker processes "
-                         "(deterministic grid-order results either way)")
+                         "(deterministic grid-order results either way; "
+                         "CPU-only: needs JAX_PLATFORMS=cpu)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     fast = not args.full
     rows = []
 

@@ -30,8 +30,9 @@ in *how* the cohort is scheduled:
   stacked per-client data, padded to the next power of two so XLA compiles
   at most log2(K)+1 cohort shapes per task.
 - ``sharded`` — the vmap step with the client axis sharded across a
-  ``launch/mesh.py`` device mesh (pure data parallelism over clients);
-  falls back to ``vmap`` on single-device hosts.
+  ``launch/mesh.py`` device mesh (pure data parallelism over clients),
+  aggregated as per-device partial sums plus one all-reduce; falls back
+  to ``vmap`` on single-device hosts.
 
 ``aggregate(stacked_updates, weights, normalizer=None)`` computes the
 weighted sum ``sum_k (w_k / max(normalizer, 1e-12)) * update_k`` per leaf
@@ -234,12 +235,7 @@ class VmapBackend:
         return CohortResult(jax.tree.map(lambda leaf: leaf[:n], updates), losses[:n])
 
     def aggregate(self, stacked_updates, weights, normalizer=None):
-        norm = _norm_weights(weights, normalizer)
-        if jax.default_backend() == "cpu":
-            # interpret-mode Pallas is a correctness oracle, not a fast
-            # path — on CPU the jnp weighted sum IS the compiled path
-            return _weighted_sum_jnp(stacked_updates, norm)
-        return _pallas_aggregate(stacked_updates, norm)
+        return _cohort_sum()(stacked_updates, _norm_weights(weights, normalizer))
 
 
 @register_backend("sharded")
@@ -286,8 +282,50 @@ class ShardedBackend(VmapBackend):
         updates, losses = fn(params, keys, *data)
         return CohortResult(jax.tree.map(lambda leaf: leaf[:n], updates), losses[:n])
 
+    def aggregate(self, stacked_updates, weights, normalizer=None):
+        """Each device reduces its own slice of the cohort, then one
+        all-reduce of the N-sized partial sums: the K x N cohort is never
+        gathered. A Pallas kernel cannot be partitioned by the compiler,
+        so the per-device reduce runs inside ``shard_map``."""
+        if jax.device_count() <= 1:
+            return super().aggregate(stacked_updates, weights, normalizer)
+        norm = _norm_weights(weights, normalizer)
+        mesh = self._cohort_mesh()
+        k = norm.shape[0]
+        padded = k + (-k) % mesh.devices.size
+        # padded rows repeat the last client at weight 0: they add nothing
+        stacked_updates = _pad_cohort(stacked_updates, k, padded)
+        norm = jnp.pad(norm, (0, padded - k))
+        return _sharded_fold(mesh, _cohort_sum())(stacked_updates, norm)
+
 
 # ----------------------------------------------------- compiled aggregation
+
+
+def _cohort_sum():
+    """The weighted cohort sum for this platform: the Pallas kernel where
+    it compiles (TPU/GPU). Interpret-mode Pallas is a correctness oracle,
+    not a fast path, so on CPU the jnp weighted sum IS the compiled path."""
+    return _weighted_sum_jnp if jax.default_backend() == "cpu" else _pallas_aggregate
+
+
+def _sharded_fold(mesh, local):
+    """jit of ``local(stacked, norm)`` over each device's slice of the
+    ``"clients"`` axis of ``mesh``, summed across devices."""
+    from jax.sharding import PartitionSpec
+
+    key = (mesh, local, "fold")
+    got = _TRANSFORMS.get(key)
+    if got is None:
+        clients = PartitionSpec("clients")
+        # check_vma=False: a pallas_call's out_shape carries no
+        # per-axis variance annotation for the checker to read
+        got = jax.jit(jax.shard_map(
+            lambda x, w: jax.lax.psum(local(x, w), "clients"), mesh=mesh,
+            in_specs=(clients, clients), out_specs=PartitionSpec(),
+            check_vma=False))
+        _TRANSFORMS[key] = got
+    return got
 
 
 def _pallas_aggregate(stacked_updates, norm):

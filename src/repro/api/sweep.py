@@ -13,7 +13,8 @@ are a single call instead of driver plumbing:
 ``max_workers=N`` runs the grid points in N worker PROCESSES
 (spawn-context ``ProcessPoolExecutor``; each worker re-imports jax and
 rebuilds the spec from JSON), with results merged in grid order so the
-payload is deterministic regardless of completion order. Grid points
+payload is deterministic regardless of completion order. It is CPU-only
+(``JAX_PLATFORMS=cpu``): an accelerator belongs to one process. Grid points
 must only reference registry keys importable from ``repro.*`` — a spec
 using an in-process custom registration needs the sequential path.
 
@@ -25,6 +26,7 @@ from __future__ import annotations
 
 import copy
 import json
+import os
 import time
 from itertools import product
 from typing import Any, Dict, List, Optional, Sequence
@@ -105,6 +107,15 @@ def sweep_scenarios(
     axes, points = _grid_points(base_spec, grid)
     runs: List[Dict[str, Any]] = []
     if max_workers is not None and max_workers > 1:
+        # an accelerator belongs to one process: only CPU workers can run
+        # side by side (read from the environment, so that this parent
+        # never takes the chip itself)
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            raise ValueError(
+                f"sweep_scenarios(max_workers={max_workers}) runs grid points in "
+                "worker processes, and only one process can hold an accelerator; "
+                "set JAX_PLATFORMS=cpu for a parallel CPU sweep, or run the "
+                "points in this process (max_workers=1)")
         # spawn (not fork): jax state must not be inherited mid-flight
         import multiprocessing as mp
         from concurrent.futures import ProcessPoolExecutor

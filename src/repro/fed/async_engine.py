@@ -557,6 +557,9 @@ class AsyncMMFLEngine:
                     self._release(s, v)
             stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves),
                                    *deltas)
+            # release the per-client copies before the fold: at published
+            # widths each is a cohort-sized block of device memory
+            del deltas, cohort
             # FedAST staleness discount on the weights, normalised by the
             # UNDISCOUNTED sum (fed.server.aggregate_stale semantics),
             # folded by the pluggable aggregator ("fedavg" dispatches the

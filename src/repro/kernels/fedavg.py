@@ -35,8 +35,10 @@ FUSED_MODES = ("fedavg", "fedavgm", "fedadam", "fedyogi")
 def _fedavg_kernel(w_ref, x_ref, o_ref):
     w = w_ref[...]                                 # (1, K)
     x = x_ref[...]                                 # (K, blk)
+    # HIGHEST: an f32 average of absolute params must not round them to
+    # bf16 on the MXU
     o_ref[...] = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())),
+        w, x, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -118,7 +120,7 @@ def _fused_kernel(w_ref, s_ref, c_ref, x_ref, m_ref, v_ref,
     disc = w * jnp.exp(-beta * jnp.log1p(st)) * inv_norm
     x = x_ref[...]                                 # (K, blk) delta tile
     d = jax.lax.dot_general(
-        disc, x, (((1,), (0,)), ((), ())),
+        disc, x, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)        # (1, blk)
     if mode == "fedavg":
         o_ref[...] = lr * d

@@ -336,6 +336,9 @@ def build_scenario(args) -> ScenarioSpec:
 
 
 def main():
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--spec", default=None,
                     help="ScenarioSpec JSON file; overrides all other "

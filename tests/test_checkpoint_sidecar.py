@@ -12,6 +12,7 @@ import json
 import os
 import shutil
 
+import jax
 import numpy as np
 
 from repro.api import (ClientPopulationSpec, RuntimeSpec, ScenarioSpec,
@@ -83,7 +84,14 @@ def test_legacy_async_checkpoint_fixture_resumes(tmp_path):
     assert "history_offset" not in meta and "engine" not in meta
     assert not os.path.exists(f"{d}/history.jsonl")
 
-    res = run_scenario(spec)
+    # The fixture was recorded with JAX's pre-0.5 random bits: since 0.5,
+    # jax_threefry_partitionable defaults to True and draws other bits
+    # from the same keys, so the retrained tail would train on other
+    # minibatches (and, for these synthetic tasks, loss is 1 - test
+    # accuracy). Resuming under the recording's stream keeps every
+    # comparison below as strict as when the fixture was made.
+    with jax.threefry_partitionable(False):
+        res = run_scenario(spec)
     # the full-run curves, not just the post-resume tail; the restored
     # prefix is exact (pure JSON replay), the retrained tail allclose
     np.testing.assert_allclose(res.loss, np.asarray(expected["loss"]),
@@ -109,7 +117,8 @@ def test_legacy_async_checkpoint_fixture_resumes(tmp_path):
     assert 0 < meta["history_offset"] <= \
         os.path.getsize(f"{d}/history.jsonl")
     # and a SECOND resume now replays purely from the sidecar
-    again = run_scenario(spec)
+    with jax.threefry_partitionable(False):
+        again = run_scenario(spec)
     assert_async_equal(res, again)
 
 

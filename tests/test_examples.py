@@ -13,6 +13,8 @@ def run(args, timeout=420):
     # Force the CPU platform: with an unset JAX_PLATFORMS a libtpu install
     # without TPU hardware spends minutes in init retry backoff.
     env["JAX_PLATFORMS"] = "cpu"
+    # the CLIs turn on the persistent compilation cache; tests must not
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     # Redirect to files rather than capture_output pipes: on some sandboxed
     # kernels a jax child writing to a pipe runs an order of magnitude
     # slower than one writing to a file.

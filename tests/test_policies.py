@@ -479,3 +479,19 @@ def test_parallel_sweep_matches_sequential_and_keeps_order():
         assert a["overrides"] == b["overrides"]
         np.testing.assert_array_equal(np.asarray(a["result"]["loss"]),
                                       np.asarray(b["result"]["loss"]))
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu", "cpu,tpu"])
+def test_parallel_sweep_refuses_unless_cpu(monkeypatch, platforms):
+    """Worker processes cannot share an accelerator: --jobs N needs
+    JAX_PLATFORMS=cpu, read from the environment before any worker
+    starts."""
+    from repro.api import sweep_scenarios
+
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    grid = {"allocation.strategy": ["fedfair", "random"]}
+    with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
+        sweep_scenarios(two_task_spec(rounds=1, tau=1), grid, max_workers=2)
