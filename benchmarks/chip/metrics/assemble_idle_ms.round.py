@@ -1,0 +1,2 @@
+"""Device-idle ms per sync round under input assembly (mmfl.assemble)."""
+from _spans import assemble_idle_ms as read  # noqa: F401

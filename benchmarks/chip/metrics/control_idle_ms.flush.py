@@ -1,0 +1,2 @@
+"""Device-idle ms per async flush under the host control plane's own code."""
+from _spans import control_idle_ms as read  # noqa: F401

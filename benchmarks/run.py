@@ -1,5 +1,7 @@
-"""Benchmark gate: one section per paper table/figure + kernel microbench +
-roofline summary. Prints ``name,us_per_call,derived`` CSV lines.
+"""Benchmark gate: one section per paper table/figure. Prints
+``name,us_per_call,derived`` CSV lines, ``us_per_call`` being the
+experiment's wall time on this host (a CPU run: not a device speed; the
+chip benchmark is ``benchmarks/chip/run.py``).
 
   PYTHONPATH=src python -m benchmarks.run [--full]
 
@@ -11,39 +13,6 @@ import argparse
 import json
 import sys
 import time
-
-
-def _time_us(fn, warmup=1, iters=3):
-    import jax
-    for _ in range(warmup):
-        jax.block_until_ready(fn())
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        jax.block_until_ready(fn())
-    return (time.perf_counter() - t0) / iters * 1e6
-
-
-def kernel_micro():
-    import jax
-    import jax.numpy as jnp  # noqa: F401
-    from repro.kernels import fedavg_aggregate, flash_attention, ssd_scan
-    k = jax.random.PRNGKey(0)
-    rows = []
-    q = jax.random.normal(k, (1, 4, 256, 64))
-    kk = jax.random.normal(k, (1, 2, 256, 64))
-    v = jax.random.normal(k, (1, 2, 256, 64))
-    us = _time_us(lambda: flash_attention(q, kk, v))
-    rows.append(("kernel_flash_attention_256", us, "interpret=True"))
-    x = jax.random.normal(k, (1, 2, 256, 32))
-    a = -jax.nn.softplus(jax.random.normal(k, (1, 2, 256)))
-    b = 0.3 * jax.random.normal(k, (1, 2, 256, 16))
-    us = _time_us(lambda: ssd_scan(x, a, b, b, chunk=64))
-    rows.append(("kernel_ssd_scan_256", us, "interpret=True"))
-    st = jax.random.normal(k, (16, 100_000))
-    w = jax.nn.softmax(jax.random.normal(k, (16,)))
-    us = _time_us(lambda: fedavg_aggregate(st, w))
-    rows.append(("kernel_fedavg_16x100k", us, "interpret=True"))
-    return rows
 
 
 def experiment_specs():
@@ -81,8 +50,8 @@ def main():
                     help="run a single experiment (full name or unique "
                          "prefix, e.g. 'exp4')")
     ap.add_argument("--smoke", action="store_true",
-                    help="CI smoke: async-vs-sync experiment + kernel "
-                         "microbench only (alias for --only exp9)")
+                    help="CI smoke: the async-vs-sync experiment only "
+                         "(alias for --only exp9)")
     ap.add_argument("--json-out", default=None,
                     help="also write the rows as JSON (CI artifact)")
     ap.add_argument("--sweep", default=None, metavar="SPEC_JSON",
@@ -151,22 +120,6 @@ def main():
             rows.append((name, us, json.dumps(result, sort_keys=True)))
             print(f"# {name}: {json.dumps(result, sort_keys=True)[:220]}",
                   file=sys.stderr)
-
-    rows.extend(kernel_micro())
-
-    # roofline summary from the dry-run sweep, if present
-    try:
-        from benchmarks.roofline import load, table
-        recs = load("benchmarks/results/dryrun")
-        tab = table(recs)
-        if tab:
-            n_coll = sum(1 for r in tab if r["bottleneck"] == "collective")
-            n_mem = sum(1 for r in tab if r["bottleneck"] == "memory")
-            rows.append(("roofline_pairs", 0.0,
-                         f"pairs={len(tab)};collective_bound={n_coll};"
-                         f"memory_bound={n_mem}"))
-    except Exception as e:  # noqa: BLE001
-        rows.append(("roofline_pairs", 0.0, f"unavailable:{e}"))
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.api.registry import BACKENDS, register_backend
 
 # ---------------------------------------------------------------- data model
@@ -197,6 +198,8 @@ class SerialBackend:
 
     def run_cohort(self, task_state, client_batch, rng=None):
         fn = _jit_single(task_state.local_fn)
+        spans.count("cohort_rows", len(client_batch))
+        spans.count("cohort_padded_rows", len(client_batch))
         updates, losses = [], []
         for i in range(len(client_batch)):
             key_i = None if client_batch.keys is None else client_batch.keys[i]
@@ -224,6 +227,8 @@ class VmapBackend:
     def _prepare(self, client_batch):
         n = len(client_batch)
         padded = _pad_pow2(n)
+        spans.count("cohort_rows", n)
+        spans.count("cohort_padded_rows", padded)
         keys = _pad_cohort(client_batch.keys, n, padded)
         data = tuple(_pad_cohort(d, n, padded) for d in client_batch.data)
         return n, keys, data
@@ -270,6 +275,8 @@ class ShardedBackend(VmapBackend):
         # rows, sliced off on return) so the shard split is even
         padded = max(_pad_pow2(n), n_shards)
         padded += (-padded) % n_shards
+        spans.count("cohort_rows", n)
+        spans.count("cohort_padded_rows", padded)
         cohort_sharding = NamedSharding(mesh, PartitionSpec("clients"))
         replicated = NamedSharding(mesh, PartitionSpec())
         params = jax.device_put(task_state.params, replicated)

@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional, Protocol
 
 import numpy as np
 
+from repro import spans
 from repro.api.backend import ClientBatch, CohortTask, get_backend
 from repro.api.policy import (  # noqa: F401  (re-exported legacy names)
     BID_MODELS,
@@ -525,7 +526,7 @@ class ArchSyncEngine:
 
     def _acc_of(self, name: str) -> float:
         """Current next-token eval accuracy of one task's global params."""
-        return float(self._eval_acc[name](self.tasks[name]["params"]))
+        return spans.fetch(self._eval_acc[name](self.tasks[name]["params"]))
 
     def _run_task_round(self, name: str, ids, rng, want_norm: bool = False):
         """One task's round: cohort execution + aggregation through the
@@ -537,40 +538,44 @@ class ArchSyncEngine:
         from repro.launch.train import assemble_batch
 
         t = self.tasks[name]
-        w = self.coord.client_weights(ids)
-        batch = assemble_batch(t, self.data[name], ids, w, rng)
-        if t["tau"] <= 1:
-            # fused server step as a SINGLE-unit cohort (state = params+opt;
-            # the p_k weighting lives inside the batch's client_weights)
-            job = ClientBatch(ids[:1], None, (jax.tree.map(lambda v: v[None], batch),))
-            state = CohortTask(name, (t["params"], t["opt"]), t["opt_local_fn"])
+        with spans.span("assemble"):
+            w = self.coord.client_weights(ids)
+            batch = assemble_batch(t, self.data[name], ids, w, rng)
+            if t["tau"] <= 1:
+                # fused server step as a SINGLE-unit cohort (state =
+                # params+opt; the p_k weighting lives inside the batch's
+                # client_weights)
+                job = ClientBatch(ids[:1], None, (jax.tree.map(lambda v: v[None], batch),))
+                state = CohortTask(name, (t["params"], t["opt"]), t["opt_local_fn"])
+            else:
+                # TRUE FedAvg: one cohort row per batch row (clients tiled
+                # to the task batch size, as assemble_batch lays them out)
+                w_rows = batch["client_weights"]
+                rows = {k: v[:, None] for k, v in batch.items() if k != "client_weights"}
+                reps = int(np.ceil(len(w_rows) / max(len(ids), 1)))
+                row_ids = np.tile(np.asarray(ids), reps)[: len(w_rows)]
+                job = ClientBatch(row_ids, None, (rows,))
+                state = CohortTask(name, t["params"], t["local_fn"])
+        with spans.span("cohort"):
             res = self.backend.run_cohort(state, job)
-            norm = None
+        norm = None
+        if t["tau"] <= 1:
             if want_norm:
                 # displacement of the params (not opt-state) from the step
-                norm = float(stacked_delta_norms(res.updates[0], t["params"])[0])
-            t["params"], t["opt"] = jax.tree.map(lambda leaf: leaf[0], res.updates)
-            return float(res.losses[0]), norm
-        # TRUE FedAvg: one cohort row per batch row (clients tiled to the
-        # task batch size, as assemble_batch lays them out)
-        w_rows = batch["client_weights"]
-        rows = {k: v[:, None] for k, v in batch.items() if k != "client_weights"}
-        reps = int(np.ceil(len(w_rows) / max(len(ids), 1)))
-        row_ids = np.tile(np.asarray(ids), reps)[: len(w_rows)]
-        res = self.backend.run_cohort(
-            CohortTask(name, t["params"], t["local_fn"]),
-            ClientBatch(row_ids, None, (rows,)),
-        )
-        norm = None
+                norm = spans.fetch(stacked_delta_norms(res.updates[0], t["params"])[0])
+            with spans.span("fold"):
+                t["params"], t["opt"] = jax.tree.map(lambda leaf: leaf[0], res.updates)
+            return spans.fetch(res.losses[0]), norm
         if want_norm:
-            norm = float(stacked_delta_norms(res.updates, t["params"]).mean())
+            norm = spans.fetch(stacked_delta_norms(res.updates, t["params"]).mean())
         # pluggable server fold ("fedavg" = the direct backend weighted
         # mean over absolute cohort params, the bit-exact legacy trace)
-        t["params"], self._server_state[name] = self.aggregator.aggregate_params(
-            t["params"], res.updates, w_rows, self._server_state[name],
-            normalizer=jnp.maximum(w_rows.sum(), 1e-9)
-        )
-        return float(res.losses.mean()), norm
+        with spans.span("fold"):
+            t["params"], self._server_state[name] = self.aggregator.aggregate_params(
+                t["params"], res.updates, w_rows, self._server_state[name],
+                normalizer=jnp.maximum(w_rows.sum(), 1e-9)
+            )
+        return spans.fetch(res.losses.mean()), norm
 
     def run(self, verbose: bool = False) -> RunResult:
         spec, rt = self.spec, self.spec.runtime
@@ -684,97 +689,99 @@ class ArchSyncEngine:
         want_norms = self.coord.wants_update_norms
         clock = clock_hist[-1] if clock_hist else 0.0
         for r in range(start_round, rt.rounds):
-            if self.incentive is not None:
-                upd = self.incentive.recruit(
-                    RoundContext(
-                        round=r,
-                        task_names=self.names,
-                        losses=self.coord.losses,
-                        alpha=spec.allocation.alpha,
-                        n_clients=spec.clients.n_clients,
-                        eligibility=self.coord.eligibility,
-                    )
-                )
-                if upd is not None:
-                    self.coord.eligibility = self._set_eligibility(upd.eligibility)
-            alloc = self.coord.next_round()
-            t0 = time.time()
-            line = []
-            row = np.full(spec.clients.n_clients, -1, np.int64)
-            norms = np.full(len(self.names), np.nan) if want_norms else None
-            # simulated round duration: the lockstep barrier waits for
-            # the slowest sampled (client, task) latency this round
-            round_time = 0.0
-            for s, a in enumerate(self.names):
-                ids = alloc[a]
-                if len(ids) == 0:
-                    line.append(f"{a}: -")
-                    continue
-                row[ids] = s
-                if self.population is not None:
-                    # cohort-batched latency sampling (same stream order)
-                    totals, _ = self.population.sample_latencies(
-                        ids, s, 1.0, times=clock)
-                    round_time = max(round_time, float(totals.max()))
-                else:
-                    for i in ids:
-                        round_time = max(
-                            round_time,
-                            self.cost_model.sample_latency(
-                                int(i), s, 1.0, time=clock).total)
-                loss, norm = self._run_task_round(a, ids, rng, want_norms)
-                if want_norms and norm is not None:
-                    norms[s] = norm
-                self.coord.report(a, loss)
-                line.append(f"{a}: {loss:.3f} ({len(ids)}c)")
-            self.coord.observe([len(alloc[a]) for a in self.names], norms)
-            loss_hist.append([self.coord.tasks[a].loss for a in self.names])
-            count_hist.append([len(alloc[a]) for a in self.names])
-            alloc_hist.append(row)
-            acc_hist.append([self._acc_of(a) for a in self.names])
-            clock += round_time
-            clock_hist.append(clock)
-            if ckpt is not None:
-                # whole-run history streams into the append-only sidecar
-                # (buffered; the next save fsyncs + commits the offset)
-                ckpt.append_history({
-                    "kind": "round",
-                    "loss": list(loss_hist[-1]),
-                    "counts": list(count_hist[-1]),
-                    "alloc": row.tolist(),
-                    "acc": list(acc_hist[-1]),
-                    "wall_clock": float(clock),
-                })
-            if verbose:
-                print(f"round {r + 1:3d} [{time.time() - t0:5.1f}s] " + " | ".join(line))
-            if ckpt and (r + 1) % rt.checkpoint_every == 0:
-                task_state = {}
-                for a in self.names:
-                    task_state[a] = {
-                        "params": self.tasks[a]["params"],
-                        "opt": self.tasks[a]["opt"],
-                    }
-                    # optimizer moments of a stateful aggregator ride
-                    # with the model pytrees; omitted for stateless
-                    # rules so fedavg keeps the pre-aggregator layout
-                    if self._server_state[a] is not None:
-                        task_state[a]["server_state"] = self._server_state[a]
-                coord_payload = {
-                    "coordinator": self.coord.state_dict(),
-                    "data_rng": rng.bit_generator.state,
-                    "aggregator": self.aggregator.state_dict(),
-                    "cost_model": self.cost_model.state_dict(),
-                }
-                if self.population is not None:
-                    coord_payload["population"] = \
-                        self.population.config_record()
+            with spans.step("round", r):
                 if self.incentive is not None:
-                    coord_payload["incentive"] = self.incentive.state_dict()
-                # NOTE: no history in the step payload — the whole-run
-                # curves live in the sidecar (O(1) checkpoint size)
-                ckpt.save(r + 1, task_state,
-                          coordinator_state=coord_payload,
-                          engine_kind="sync")
+                    upd = self.incentive.recruit(
+                        RoundContext(
+                            round=r,
+                            task_names=self.names,
+                            losses=self.coord.losses,
+                            alpha=spec.allocation.alpha,
+                            n_clients=spec.clients.n_clients,
+                            eligibility=self.coord.eligibility,
+                        )
+                    )
+                    if upd is not None:
+                        self.coord.eligibility = self._set_eligibility(upd.eligibility)
+                alloc = self.coord.next_round()
+                t0 = time.time()
+                line = []
+                row = np.full(spec.clients.n_clients, -1, np.int64)
+                norms = np.full(len(self.names), np.nan) if want_norms else None
+                # simulated round duration: the lockstep barrier waits for
+                # the slowest sampled (client, task) latency this round
+                round_time = 0.0
+                for s, a in enumerate(self.names):
+                    ids = alloc[a]
+                    if len(ids) == 0:
+                        line.append(f"{a}: -")
+                        continue
+                    row[ids] = s
+                    if self.population is not None:
+                        # cohort-batched latency sampling (same stream order)
+                        totals, _ = self.population.sample_latencies(
+                            ids, s, 1.0, times=clock)
+                        round_time = max(round_time, float(totals.max()))
+                    else:
+                        for i in ids:
+                            round_time = max(
+                                round_time,
+                                self.cost_model.sample_latency(
+                                    int(i), s, 1.0, time=clock).total)
+                    loss, norm = self._run_task_round(a, ids, rng, want_norms)
+                    if want_norms and norm is not None:
+                        norms[s] = norm
+                    self.coord.report(a, loss)
+                    line.append(f"{a}: {loss:.3f} ({len(ids)}c)")
+                self.coord.observe([len(alloc[a]) for a in self.names], norms)
+                loss_hist.append([self.coord.tasks[a].loss for a in self.names])
+                count_hist.append([len(alloc[a]) for a in self.names])
+                alloc_hist.append(row)
+                with spans.span("eval"):
+                    acc_hist.append([self._acc_of(a) for a in self.names])
+                clock += round_time
+                clock_hist.append(clock)
+                if ckpt is not None:
+                    # whole-run history streams into the append-only sidecar
+                    # (buffered; the next save fsyncs + commits the offset)
+                    ckpt.append_history({
+                        "kind": "round",
+                        "loss": list(loss_hist[-1]),
+                        "counts": list(count_hist[-1]),
+                        "alloc": row.tolist(),
+                        "acc": list(acc_hist[-1]),
+                        "wall_clock": float(clock),
+                    })
+                if verbose:
+                    print(f"round {r + 1:3d} [{time.time() - t0:5.1f}s] " + " | ".join(line))
+                if ckpt and (r + 1) % rt.checkpoint_every == 0:
+                    task_state = {}
+                    for a in self.names:
+                        task_state[a] = {
+                            "params": self.tasks[a]["params"],
+                            "opt": self.tasks[a]["opt"],
+                        }
+                        # optimizer moments of a stateful aggregator ride
+                        # with the model pytrees; omitted for stateless
+                        # rules so fedavg keeps the pre-aggregator layout
+                        if self._server_state[a] is not None:
+                            task_state[a]["server_state"] = self._server_state[a]
+                    coord_payload = {
+                        "coordinator": self.coord.state_dict(),
+                        "data_rng": rng.bit_generator.state,
+                        "aggregator": self.aggregator.state_dict(),
+                        "cost_model": self.cost_model.state_dict(),
+                    }
+                    if self.population is not None:
+                        coord_payload["population"] = \
+                            self.population.config_record()
+                    if self.incentive is not None:
+                        coord_payload["incentive"] = self.incentive.state_dict()
+                    # NOTE: no history in the step payload — the whole-run
+                    # curves live in the sidecar (O(1) checkpoint size)
+                    ckpt.save(r + 1, task_state,
+                              coordinator_state=coord_payload,
+                              engine_kind="sync")
 
         if ckpt is not None:
             ckpt.close()

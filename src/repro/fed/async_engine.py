@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.api.aggregator import aggregator_from_config
 from repro.api.arrivals import get_arrival_process
 from repro.api.backend import ClientBatch, CohortTask, get_backend
@@ -517,110 +518,123 @@ class AsyncMMFLEngine:
         return elig
 
     def _flush(self, s: int, t: float):
-        cfg = self.cfg
-        buf = self._buffers[s]
-        self._buffers[s] = []
-        cur = self._version[s]
-        kept: List[_Job] = []
-        for j in buf:
-            if (cfg.max_staleness is not None
-                    and cur - j.version > cfg.max_staleness):
-                self._dropped += 1
-                self._release(s, j.version)
-            else:
-                kept.append(j)
-        if kept:
-            # one backend cohort dispatch per distinct dispatch version
-            task = self.tasks[s]
-            deltas, weights, stale = [], [], []
-            by_version: Dict[int, List[_Job]] = {}
-            for j in kept:
-                by_version.setdefault(j.version, []).append(j)
-            for v in sorted(by_version):
-                group = by_version[v]
-                ids = np.array([j.client for j in group], np.int64)
-                base = self._retained[s][v][0]
-                if task.local_fn is None:
-                    # legacy adapter (pre-backend protocol): only
-                    # update() is defined — honour it, without backend
-                    # dispatch
-                    cohort = task.update(base, cfg.seed, v, ids)
+        with spans.step("flush", self._n_flushes):
+            cfg = self.cfg
+            buf = self._buffers[s]
+            self._buffers[s] = []
+            cur = self._version[s]
+            kept: List[_Job] = []
+            for j in buf:
+                if (cfg.max_staleness is not None
+                        and cur - j.version > cfg.max_staleness):
+                    self._dropped += 1
+                    self._release(s, j.version)
                 else:
-                    cohort = self.backend.run_cohort(
-                        CohortTask(task.name, base, task.local_fn),
-                        task.client_batch(cfg.seed, v, ids)).updates
-                for i, j in enumerate(group):
-                    deltas.append(jax.tree.map(
-                        lambda c, b: c[i] - b, cohort, base))
-                    weights.append(task.p_k[j.client])
-                    stale.append(cur - v)
-                    self._release(s, v)
-            stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves),
-                                   *deltas)
-            # release the per-client copies before the fold: at published
-            # widths each is a cohort-sized block of device memory
-            del deltas, cohort
-            # FedAST staleness discount on the weights, normalised by the
-            # UNDISCOUNTED sum (fed.server.aggregate_stale semantics),
-            # folded by the pluggable aggregator ("fedavg" dispatches the
-            # weighted sum through the backend — the bit-exact legacy
-            # trace; stateful server optimizers fuse discount + reduce +
-            # moment update into one Pallas pass on compiled platforms)
-            w = jnp.asarray(np.asarray(weights, np.float32))
-            agg, self._server_state[s] = self.aggregator.aggregate_stale(
-                stacked, w, np.asarray(stale, np.float32), cfg.beta,
-                self._server_state[s], normalizer=w.sum())
-            self._params[s] = jax.tree.map(
-                lambda p, d: p + cfg.server_lr * d, self._params[s], agg)
-            self._version[s] = cur + 1
-            self._metric[s] = task.evaluate(self._params[s])
-            self.coord.report(task.name, self._metric[s])
-            # policy feedback: this flush's allocation counts (and, when
-            # the policy opts in, the mean delta norm of the buffer)
-            counts = np.zeros(self.S, np.int64)
-            counts[s] = len(kept)
-            norms = None
-            if self.coord.wants_update_norms:
-                norms = np.full(self.S, np.nan)
-                norms[s] = float(stacked_delta_norms(stacked).mean())
-            self.coord.observe(counts, norms, task=s)
-            self._n_flushes += 1
-            if self.incentive is not None:
-                upd = self.incentive.recruit(RoundContext(
-                    round=self._n_flushes,
-                    task_names=self.coord.task_names,
-                    losses=self.coord.losses, alpha=cfg.alpha,
-                    n_clients=self.K,
-                    eligibility=self.coord.eligibility))
-                if upd is not None:
-                    self.coord.eligibility = self._set_eligibility(
-                        upd.eligibility)
-            if self._has_acc:
-                self._acc[s] = float(task.accuracy(self._params[s]))
-                self._hist_acc.append(self._acc.copy())
-            stale_mean = float(np.mean(stale))
-            # adaptive buffer sizing: the controller sees this flush's
-            # staleness/arrival feedback and emits the per-task sizes in
-            # force from the NEXT arrival on ("static" never moves them)
-            self.controller.observe(FlushObservation(
-                flush=self._n_flushes, task=s, time=float(t),
-                staleness_mean=stale_mean, kept=len(kept),
-                arrivals=self._arrivals.copy(),
-                sizes=self._buffer_sizes.copy()))
-            self._buffer_sizes = np.asarray(self.controller.sizes(),
-                                            np.int64).copy()
-            self._hist_time.append(t)
-            self._hist_task.append(s)
-            self._hist_metric.append(self._metric.copy())
-            self._hist_stale.append(stale_mean)
-            self._hist_bufsz.append(self._buffer_sizes.copy())
-            rec = {"kind": "flush", "time": float(t), "task": int(s),
-                   "metric": [float(x) for x in self._metric],
-                   "stale": float(stale_mean),
-                   "buffer_sizes": [int(x) for x in self._buffer_sizes]}
-            if self._has_acc:
-                rec["acc"] = [float(x) for x in self._acc]
-            self._record(rec)
+                    kept.append(j)
+            if kept:
+                # one backend cohort dispatch per distinct dispatch version
+                task = self.tasks[s]
+                deltas, weights, stale = [], [], []
+                by_version: Dict[int, List[_Job]] = {}
+                for j in kept:
+                    by_version.setdefault(j.version, []).append(j)
+                for v in sorted(by_version):
+                    group = by_version[v]
+                    ids = np.array([j.client for j in group], np.int64)
+                    base = self._retained[s][v][0]
+                    if task.local_fn is None:
+                        # legacy adapter (pre-backend protocol): only
+                        # update() is defined — honour it, without backend
+                        # dispatch
+                        cohort = task.update(base, cfg.seed, v, ids)
+                    else:
+                        with spans.span("assemble"):
+                            batch = task.client_batch(cfg.seed, v, ids)
+                        with spans.span("cohort"):
+                            cohort = self.backend.run_cohort(
+                                CohortTask(task.name, base, task.local_fn),
+                                batch).updates
+                    with spans.span("deltas"):
+                        for i, j in enumerate(group):
+                            deltas.append(jax.tree.map(
+                                lambda c, b: c[i] - b, cohort, base))
+                            weights.append(task.p_k[j.client])
+                            stale.append(cur - v)
+                            self._release(s, v)
+                with spans.span("deltas"):
+                    stacked = jax.tree.map(
+                        lambda *leaves: jnp.stack(leaves), *deltas)
+                # release the per-client copies before the fold: at published
+                # widths each is a cohort-sized block of device memory
+                del deltas, cohort
+                # FedAST staleness discount on the weights, normalised by the
+                # UNDISCOUNTED sum (fed.server.aggregate_stale semantics),
+                # folded by the pluggable aggregator ("fedavg" dispatches the
+                # weighted sum through the backend — the bit-exact legacy
+                # trace; stateful server optimizers fuse discount + reduce +
+                # moment update into one Pallas pass on compiled platforms)
+                with spans.span("fold"):
+                    w = jnp.asarray(np.asarray(weights, np.float32))
+                    agg, self._server_state[s] = \
+                        self.aggregator.aggregate_stale(
+                            stacked, w, np.asarray(stale, np.float32),
+                            cfg.beta, self._server_state[s],
+                            normalizer=w.sum())
+                    self._params[s] = jax.tree.map(
+                        lambda p, d: p + cfg.server_lr * d, self._params[s],
+                        agg)
+                self._version[s] = cur + 1
+                with spans.span("eval"):
+                    self._metric[s] = task.evaluate(self._params[s])
+                self.coord.report(task.name, self._metric[s])
+                # policy feedback: this flush's allocation counts (and, when
+                # the policy opts in, the mean delta norm of the buffer)
+                counts = np.zeros(self.S, np.int64)
+                counts[s] = len(kept)
+                norms = None
+                if self.coord.wants_update_norms:
+                    norms = np.full(self.S, np.nan)
+                    norms[s] = spans.fetch(
+                        stacked_delta_norms(stacked).mean())
+                self.coord.observe(counts, norms, task=s)
+                self._n_flushes += 1
+                if self.incentive is not None:
+                    upd = self.incentive.recruit(RoundContext(
+                        round=self._n_flushes,
+                        task_names=self.coord.task_names,
+                        losses=self.coord.losses, alpha=cfg.alpha,
+                        n_clients=self.K,
+                        eligibility=self.coord.eligibility))
+                    if upd is not None:
+                        self.coord.eligibility = self._set_eligibility(
+                            upd.eligibility)
+                if self._has_acc:
+                    with spans.span("eval"):
+                        self._acc[s] = float(task.accuracy(self._params[s]))
+                    self._hist_acc.append(self._acc.copy())
+                stale_mean = float(np.mean(stale))
+                # adaptive buffer sizing: the controller sees this flush's
+                # staleness/arrival feedback and emits the per-task sizes in
+                # force from the NEXT arrival on ("static" never moves them)
+                self.controller.observe(FlushObservation(
+                    flush=self._n_flushes, task=s, time=float(t),
+                    staleness_mean=stale_mean, kept=len(kept),
+                    arrivals=self._arrivals.copy(),
+                    sizes=self._buffer_sizes.copy()))
+                self._buffer_sizes = np.asarray(self.controller.sizes(),
+                                                np.int64).copy()
+                self._hist_time.append(t)
+                self._hist_task.append(s)
+                self._hist_metric.append(self._metric.copy())
+                self._hist_stale.append(stale_mean)
+                self._hist_bufsz.append(self._buffer_sizes.copy())
+                rec = {"kind": "flush", "time": float(t), "task": int(s),
+                       "metric": [float(x) for x in self._metric],
+                       "stale": float(stale_mean),
+                       "buffer_sizes": [int(x) for x in self._buffer_sizes]}
+                if self._has_acc:
+                    rec["acc"] = [float(x) for x in self._acc]
+                self._record(rec)
 
     # -- checkpoint state --------------------------------------------------
 
@@ -934,49 +948,50 @@ class AsyncMMFLEngine:
         self._state_loaded = False
 
         while self._processed < cfg.total_arrivals and self._events:
-            t, _, job = heapq.heappop(self._events)
-            self._processed += 1
-            if job.dropout:
-                # cost-model dropout: the client was occupied until now
-                # but contributes NO update — release the pinned model
-                # version and re-enqueue the client on its next fair
-                # assignment. Counts against total_arrivals (the client
-                # spent the time) but not the per-task arrival tallies.
-                self._cost_dropouts += 1
-                self._release(job.task, job.version)
+            with spans.span("event"):
+                t, _, job = heapq.heappop(self._events)
+                self._processed += 1
+                if job.dropout:
+                    # cost-model dropout: the client was occupied until now
+                    # but contributes NO update — release the pinned model
+                    # version and re-enqueue the client on its next fair
+                    # assignment. Counts against total_arrivals (the client
+                    # spent the time) but not the per-task arrival tallies.
+                    self._cost_dropouts += 1
+                    self._release(job.task, job.version)
+                    self._dispatch(job.client, t)
+                    continue
+                self._arrivals[job.task] += 1
+                self._per_client[job.client] += 1
+                self._buffers[job.task].append(job)
+                flushes_before = self._n_flushes
+                if len(self._buffers[job.task]) >= \
+                        self._buffer_sizes[job.task]:
+                    self._flush(job.task, t)
+                    # a controller may have SHRUNK other tasks' sizes below
+                    # their current occupancy: sweep so a starved task's
+                    # buffered updates flush promptly instead of aging until
+                    # its own next (rare) arrival. A no-op under "static"
+                    # (sizes never move, so no other buffer is at threshold).
+                    swept = True
+                    while swept:
+                        swept = False
+                        for s in range(self.S):
+                            if (self._buffers[s] and len(self._buffers[s])
+                                    >= self._buffer_sizes[s]):
+                                self._flush(s, t)
+                                swept = True
                 self._dispatch(job.client, t)
-                continue
-            self._arrivals[job.task] += 1
-            self._per_client[job.client] += 1
-            self._buffers[job.task].append(job)
-            flushes_before = self._n_flushes
-            if len(self._buffers[job.task]) >= \
-                    self._buffer_sizes[job.task]:
-                self._flush(job.task, t)
-                # a controller may have SHRUNK other tasks' sizes below
-                # their current occupancy: sweep so a starved task's
-                # buffered updates flush promptly instead of aging until
-                # its own next (rare) arrival. A no-op under "static"
-                # (sizes never move, so no other buffer is at threshold).
-                swept = True
-                while swept:
-                    swept = False
-                    for s in range(self.S):
-                        if (self._buffers[s] and len(self._buffers[s])
-                                >= self._buffer_sizes[s]):
-                            self._flush(s, t)
-                            swept = True
-            self._dispatch(job.client, t)
-            if verbose and self._processed % 50 == 0:
-                f = " ".join(f"{m:.3f}" for m in self._metric)
-                print(f"  arrival {self._processed:5d} t={t:8.2f} "
-                      f"f_s=[{f}]")
-            # checkpoint when the flush count CROSSES a cadence multiple
-            # (one arrival can trigger several flushes via the sweep)
-            if (ckpt is not None and cfg.checkpoint_every > 0
-                    and self._n_flushes // cfg.checkpoint_every
-                    > flushes_before // cfg.checkpoint_every):
-                self._save_checkpoint(ckpt)
+                if verbose and self._processed % 50 == 0:
+                    f = " ".join(f"{m:.3f}" for m in self._metric)
+                    print(f"  arrival {self._processed:5d} t={t:8.2f} "
+                          f"f_s=[{f}]")
+                # checkpoint when the flush count CROSSES a cadence multiple
+                # (one arrival can trigger several flushes via the sweep)
+                if (ckpt is not None and cfg.checkpoint_every > 0
+                        and self._n_flushes // cfg.checkpoint_every
+                        > flushes_before // cfg.checkpoint_every):
+                    self._save_checkpoint(ckpt)
 
         if ckpt is not None:
             ckpt.close()

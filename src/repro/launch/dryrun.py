@@ -9,8 +9,8 @@ sharding-annotated) for params, optimizer state, batches and KV caches, then
     lowered  = jax.jit(step, out_shardings=..., donate...).lower(*sds)
     compiled = lowered.compile()
 and record memory_analysis(), cost_analysis() and the collective schedule
-parsed from the post-SPMD HLO (launch/hlo_analysis.py) into a JSON blob that
-benchmarks/roofline.py consumes.
+parsed from the post-SPMD HLO (launch/hlo_analysis.py) into a JSON record
+(``--out``).
 
 NOTE: the XLA_FLAGS line above MUST precede any jax import — jax locks the
 host device count at first init. Smoke tests / benches import repro.* and

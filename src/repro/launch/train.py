@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.api import (AllocationSpec, ClientPopulationSpec, PolicySpec,
                        RuntimeSpec, ScenarioSpec, TaskSpec, run_scenario)
 from repro.configs import get_config, smoke_config
@@ -283,12 +284,12 @@ class ArchAsyncTask:
             self.client_batch(seed, version, client_ids)).updates
 
     def evaluate(self, params) -> float:
-        return float(self._eval(params))
+        return spans.fetch(self._eval(params))
 
     def accuracy(self, params) -> float:
         """Next-token top-1 accuracy on the held-out shard (the arch
         family's analogue of the synthetic tasks' test accuracy)."""
-        return float(self._eval_acc(params))
+        return spans.fetch(self._eval_acc(params))
 
 
 def build_scenario(args) -> ScenarioSpec:
