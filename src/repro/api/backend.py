@@ -240,7 +240,10 @@ class VmapBackend:
         return CohortResult(jax.tree.map(lambda leaf: leaf[:n], updates), losses[:n])
 
     def aggregate(self, stacked_updates, weights, normalizer=None):
-        return _cohort_sum()(stacked_updates, _norm_weights(weights, normalizer))
+        fold = _cohort_sum()
+        if fold is _pallas_aggregate:
+            spans.count("fold_programs")
+        return fold(stacked_updates, _norm_weights(weights, normalizer))
 
 
 @register_backend("sharded")
@@ -335,21 +338,38 @@ def _sharded_fold(mesh, local):
     return got
 
 
+def _swap_lanes(shape) -> bool:
+    """Whether a cohort leaf of ``shape`` (cohort axis first) ravels with
+    its last two axes swapped: where that puts a multiple of 128 lanes last
+    and its own last axis is not one, flattening it to the kernel's (K, N)
+    operand is a plain copy and not a relayout of every element. The fold
+    is elementwise along N, so any order of the flat axis gives the same
+    sums."""
+    return len(shape) >= 3 and shape[-1] % 128 != 0 and shape[-2] % 128 == 0
+
+
+@jax.jit
 def _pallas_aggregate(stacked_updates, norm):
-    """Route the weighted sum through the Pallas fedavg kernel: flatten the
-    cohort to (K, N), one MXU matvec per parameter block, unflatten."""
+    """Route the weighted sum through the Pallas fedavg kernel as ONE
+    compiled program per cohort tree structure, shapes and dtypes: ravel
+    the cohort to (K, N), one MXU matvec per parameter block, unravel
+    (which casts each leaf back to its own dtype). Keeping the ravel and
+    unravel inside the jit spares the eager per-leaf reshape and copy
+    programs, and the host gaps between them, that folding around it
+    would dispatch."""
     from jax.flatten_util import ravel_pytree
 
     from repro.kernels import fedavg_aggregate
 
-    flat = jax.vmap(lambda p: ravel_pytree(p)[0])(stacked_updates)
-    template = jax.tree.map(lambda leaf: leaf[0], stacked_updates)
-    _, unravel = ravel_pytree(template)
+    swap = lambda leaf: jnp.swapaxes(leaf, -1, -2)  # noqa: E731
+    lanes = jax.tree.map(lambda x: swap(x) if _swap_lanes(x.shape) else x, stacked_updates)
+    flat = jax.vmap(lambda p: ravel_pytree(p)[0])(lanes)
+    _, unravel = ravel_pytree(jax.tree.map(lambda leaf: leaf[0], lanes))
     # keep the f32 weights as-is: the kernel promotes mixed-precision
     # inputs to the common dtype (demoting normalised weights to a bf16
     # cohort dtype, the pre-fix behaviour, rounds them before the matvec)
-    agg = fedavg_aggregate(flat, norm)
-    return jax.tree.map(lambda ref, new: jnp.asarray(new, ref.dtype), template, unravel(agg))
+    out = unravel(fedavg_aggregate(flat, norm))
+    return jax.tree.map(lambda o, x: swap(o) if _swap_lanes(x.shape) else o, out, stacked_updates)
 
 
 __all__ = [

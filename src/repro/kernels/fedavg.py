@@ -4,10 +4,12 @@ w_s <- sum_k p_{k,Sel} * w_{k,s}: a weighted reduction over the client axis
 of the stacked cohort parameters. At datacenter scale this is the paper's
 per-round hot spot on the server (K x N parameter bytes streamed once).
 
-Grid (n_param_blocks,) with block (K, blk): each step loads a (K, blk) tile
+Grid (cdiv(N, blk),) with block (K, blk): each step loads a (K, blk) tile
 of the stacked params into VMEM plus the (1, K) weight row, and emits the
 (1, blk) weighted column sum via a single MXU matvec. HBM traffic = K*N
-reads + N writes, the streaming optimum.
+reads + N writes, the streaming optimum: the last block is ragged rather
+than the cohort padded, and ``fedavg_block`` sizes the tile from K so the
+per-step overhead stays small beside the bytes each step moves.
 
 ``fused_aggregate_pallas`` extends the same tiling to the async flush hot
 path (FedAST): staleness-discount + weighted-reduce + server-optimizer
@@ -26,6 +28,12 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK = 2048
 
+# fedavg tiling: each grid step should move about _STEP_BYTES of the f32
+# cohort, within _VMEM_BUDGET of the chip's scoped VMEM (16 MiB on v5e)
+_STEP_BYTES = 1 << 20
+_VMEM_BUDGET = 12 << 20
+_LANES, _SUBLANES = 128, 8
+
 # fused-kernel scalar row: [beta, inv_norm, lr, beta1, beta2, eps] padded
 # to one 128-lane f32 tile so the block shape meets the TPU minimum
 _N_SCALARS = 128
@@ -42,11 +50,23 @@ def _fedavg_kernel(w_ref, x_ref, o_ref):
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def fedavg_pallas(stacked, weights, *, blk=DEFAULT_BLOCK, interpret=None):
+def fedavg_block(k: int) -> int:
+    """The fedavg kernel's parameter tile for a K-row f32 cohort: a multiple
+    of 128 lanes that moves about ``_STEP_BYTES`` of the cohort a grid step,
+    capped so the double-buffered (K, blk) input and (1, blk) output tiles,
+    each padded to whole 8-row sublane tiles, fit ``_VMEM_BUDGET``."""
+    rows = -(-k // _SUBLANES) * _SUBLANES + _SUBLANES      # input + output
+    cap = _VMEM_BUDGET // (2 * rows * 4) // _LANES * _LANES
+    want = -(-_STEP_BYTES // (4 * k * _LANES)) * _LANES
+    return max(_LANES, min(want, cap))
+
+
+def fedavg_pallas(stacked, weights, *, blk=None, interpret=None):
     """stacked: (K, N) flat cohort params; weights: (K,) normalised.
 
     Returns (N,) the weighted average (weights are used as given — callers
-    normalise; see fed/server.py).
+    normalise; see fed/server.py). ``blk=None`` (the default) takes the
+    tile from K (``fedavg_block``).
 
     ``interpret=None`` (the default) auto-selects from the JAX platform:
     compiled on TPU/GPU, interpreter (the Python-level oracle) on CPU —
@@ -86,23 +106,22 @@ def fedavg_pallas(stacked, weights, *, blk=DEFAULT_BLOCK, interpret=None):
 @functools.partial(jax.jit, static_argnames=("blk", "interpret"))
 def _fedavg_jit(stacked, weights, *, blk, interpret):
     K, N = stacked.shape
-    blk = min(blk, N)
-    pad = (-N) % blk
-    if pad:
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-    Np = N + pad
+    blk = min(fedavg_block(K) if blk is None else blk, N)
+    # no pad: the last block is ragged. Its out-of-bounds lanes read
+    # unspecified values and their writes are dropped; each output lane
+    # reads only its own input lane, so the kept lanes are exact
     out = pl.pallas_call(
         _fedavg_kernel,
-        grid=(Np // blk,),
+        grid=(pl.cdiv(N, blk),),
         in_specs=[
             pl.BlockSpec((1, K), lambda i: (0, 0)),
             pl.BlockSpec((K, blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, blk), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, Np), stacked.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, N), stacked.dtype),
         interpret=interpret,
     )(weights[None, :], stacked)
-    return out[0, :N]
+    return out[0]
 
 
 # ------------------------------------------------- fused async aggregation

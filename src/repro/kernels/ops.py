@@ -35,9 +35,10 @@ def ssd_scan(x, a, b, c, *, chunk=128):
                            interpret=not _on_tpu())
 
 
-def fedavg_aggregate(stacked, weights, *, blk=2048):
+def fedavg_aggregate(stacked, weights, *, blk=None):
     """Weighted client-parameter aggregation (MMFL server, Alg. 1 l.12).
-    Interpret mode auto-selects from the platform (see fedavg_pallas).
+    Interpret mode auto-selects from the platform, and ``blk=None`` takes
+    the kernel's tile from the cohort size (see fedavg_pallas).
     Mixed-precision cohorts (bf16 deltas, f32 weights) are promoted to
     the common dtype for the kernel and cast back on return."""
     return fedavg_pallas(stacked, weights, blk=blk)

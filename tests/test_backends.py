@@ -203,6 +203,72 @@ def test_fedavg_pallas_interpret_auto_selects_platform():
                                atol=1e-6)
 
 
+def _mixed_cohort(k):
+    """A cohort tree with leaves of ranks 1-3 in f32 and bf16, one of them
+    ravelled with its last two axes swapped (256 lanes last, not 5): 3,827
+    floats a row, a multiple of neither 128 nor 2048."""
+    rng = np.random.default_rng(k)
+    leaf = lambda shape, dt: jnp.asarray(rng.normal(size=(k,) + shape), dt)  # noqa: E731
+    return {"w": leaf((50, 45), jnp.float32), "b": leaf((37,), jnp.bfloat16),
+            "blocks": [leaf((3, 4, 10), jnp.float32), leaf((2, 70), jnp.bfloat16),
+                       leaf((256, 5), jnp.float32)]}
+
+
+@pytest.mark.parametrize("k", [1, 3, 4])
+def test_compiled_fold_matches_eager_fold_and_jnp(k):
+    """The one-program fold is bit for bit the eager composition it
+    replaces (ravel, the kernel at a 2,048-lane tile, unravel) and within
+    f32 rounding of the jnp weighted sum, in each leaf's own dtype."""
+    from jax.flatten_util import ravel_pytree
+
+    from repro.api.backend import _pallas_aggregate, _weighted_sum_jnp
+    from repro.kernels.fedavg import fedavg_pallas
+
+    cohort = _mixed_cohort(k)
+    w = jnp.asarray(np.random.default_rng(10 + k).random(k), jnp.float32)
+    w = w / w.sum()
+    got = _pallas_aggregate(cohort, w)
+    flat = jax.vmap(lambda p: ravel_pytree(p)[0])(cohort)
+    _, unravel = ravel_pytree(jax.tree.map(lambda leaf: leaf[0], cohort))
+    eager = unravel(fedavg_pallas(flat, w, blk=2048))
+    want = _weighted_sum_jnp(cohort, w)
+    assert jax.tree.structure(got) == jax.tree.structure(cohort)
+    for g, e, j in zip(jax.tree.leaves(got), jax.tree.leaves(eager), jax.tree.leaves(want)):
+        assert g.dtype == j.dtype and g.shape == j.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+        if g.dtype == jnp.float32:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(j), atol=1e-6)
+
+
+def test_compiled_fold_compiles_once_per_tree():
+    from repro.api.backend import _pallas_aggregate
+
+    w = jnp.full(3, 1 / 3, jnp.float32)
+    _pallas_aggregate(_mixed_cohort(3), w)
+    size = _pallas_aggregate._cache_size()
+    again = jax.tree.map(lambda leaf: leaf + 1, _mixed_cohort(3))
+    jax.block_until_ready(_pallas_aggregate(again, w * 0.5))
+    assert _pallas_aggregate._cache_size() == size
+
+
+def test_fold_programs_counts_each_compiled_aggregate(tmp_path, monkeypatch):
+    """``fold_programs`` counts each aggregate that takes the compiled fold
+    (the path on TPU/GPU), once per call and only under a capture."""
+    from repro import spans
+    from repro.api import backend
+
+    monkeypatch.setattr(backend, "_cohort_sum", lambda: backend._pallas_aggregate)
+    cohort, w = _mixed_cohort(4), np.ones(4, np.float32)
+    spans.reset()
+    backend.VmapBackend().aggregate(cohort, w)
+    assert "fold_programs" not in spans.counters()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            backend.VmapBackend().aggregate(cohort, w)
+    assert spans.counters()["fold_programs"] == 3
+    spans.reset()
+
+
 def test_fedavg_pallas_validates_shapes():
     from repro.kernels.fedavg import fedavg_pallas
 

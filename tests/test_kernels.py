@@ -87,6 +87,8 @@ def test_ssd_scan_state_continuity():
 
 @pytest.mark.parametrize("K,N,blk", [
     (4, 1000, 256), (16, 4096, 2048), (7, 12345, 512),  # non-divisible N
+    (3, 2500, 1024), (5, 4160, 2048),    # ragged last block, N % 128 != 0
+    (4, 70000, None),                    # the derived tile, ragged
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fedavg_sweep(K, N, blk, dtype):
@@ -97,6 +99,19 @@ def test_fedavg_sweep(K, N, blk, dtype):
     atol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=atol)
+
+
+def test_fedavg_block_fits_vmem_and_moves_a_mebibyte():
+    from repro.kernels.fedavg import fedavg_block
+
+    for k in range(1, 17):
+        blk = fedavg_block(k)
+        assert blk % 128 == 0
+        rows = -(-k // 8) * 8 + 8           # (K, blk) in, (1, blk) out
+        assert 2 * rows * blk * 4 <= 12 << 20
+        # a mebibyte a step, unless one more lane tile would not fit
+        assert k * blk * 4 >= 1 << 20 or 2 * rows * (blk + 128) * 4 > 12 << 20
+    assert fedavg_block(4) == 64 * 1024
 
 
 def test_fedavg_matches_server_aggregate():
