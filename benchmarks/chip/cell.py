@@ -34,20 +34,6 @@ class WindowClosed(Exception):
     """Raised at the first boundary after the window's end."""
 
 
-def model_config(cfg: dict, name: str):
-    """The system's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    return ModelConfig(
-        name=name, arch_type="dense", n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], head_dim=cfg.get("head_dim") or 0,
-        qk_norm=bool(cfg.get("qk_norm")), rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]))
-
-
 def task_name(cfg: dict) -> str:
     return f"bench-{cfg['name']}"
 
@@ -158,7 +144,7 @@ class Cell:
         from repro.api.engine import ArchFamily
         from repro.configs.base import register
 
-        mc = model_config(self.cfg, self.task)
+        mc = reference.family(self.cfg).model_config(self.cfg, self.task)
         register(self.task)(lambda: mc)
         spec = build_spec(self.cfg, self.tr, self.seed, self.name)
         family = ArchFamily()

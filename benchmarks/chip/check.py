@@ -11,9 +11,10 @@ Compared, each against its own limit from ``limits/<workload>.json``:
 - ``change_norm_gap``: the same for the params' change over three steps,
   leaving out leaves whose reference gradient is under a thousandth of
   the median leaf's (they move by round-off alone);
-- ``gain_change_gap``: the same for the RMSNorm gains alone, each against
-  its own norm: weights near 1, where bfloat16's resolution (2**-7) is
-  coarser than an Adam step of 3e-3, so that a change of storage
+- ``gain_change_gap``: the same for the model family's norm gains alone
+  (its ``GAINS``), each against its own norm: weights near 1, where
+  bfloat16's resolution (2**-7) is coarser than an Adam step of 3e-3,
+  so that a change of storage
   precision shows here when it shows in no norm over a whole model;
 - ``foreign_rows``: rows the cohort trained on that are not rows of the
   seed's token shards (exact: limit 0).
@@ -33,7 +34,6 @@ import numpy as np
 import reference
 
 NOUGHT = 1e-3      # a leaf's gradient under this share of the median: round-off only
-GAINS = ("ln1", "ln2", "final_norm", "q_norm", "k_norm")
 
 
 def _rows(tokens: np.ndarray, seq: int) -> np.ndarray:
@@ -43,7 +43,7 @@ def _rows(tokens: np.ndarray, seq: int) -> np.ndarray:
 def replay(cfg: dict, tr: dict, rec, words, dtype=np.float32) -> dict:
     """The reference's losses (in the recorded order), first-step gradient
     norms and three-step change norms, from the seed's weights and the
-    recorded rows."""
+    recorded rows; with them the names of the family's gains."""
     ref = reference.reference_for(cfg, dtype)
     make = reference.weights_fn(cfg)
     p0 = ref.cast(make(words))
@@ -90,7 +90,8 @@ def replay(cfg: dict, tr: dict, rec, words, dtype=np.float32) -> dict:
     p0 = None
     versions.clear()
     return {"losses": np.asarray(losses, np.float64), "grad": grad,
-            "change": reference_norms(p, ref.cast(make(words)))}
+            "change": reference_norms(p, ref.cast(make(words))),
+            "gains": reference.family(cfg).GAINS}
 
 
 def reference_norms(a, b=None) -> np.ndarray:
@@ -137,10 +138,11 @@ def distinct_rows(rec, seq: int) -> bool:
 
 def compare(got: dict, want: dict, leaves: list) -> dict:
     """The compared numbers from two sets of readings (program or control
-    as ``got``, the float32 reference as ``want``); ``leaves`` names the
-    params' leaves in order."""
+    as ``got``, the float32 reference as ``want``, from ``replay``);
+    ``leaves`` names the params' leaves in order."""
     counted = want["grad"] >= NOUGHT * np.median(want["grad"])
-    gains = np.array([any(name.endswith(f"['{g}']") for g in GAINS) for name in leaves]) & counted
+    gains = np.array([any(name.endswith(f"['{g}']") for g in want["gains"])
+                      for name in leaves]) & counted
     gc, wc = np.asarray(got["change"], np.float64), np.asarray(want["change"], np.float64)
     lg, lw = got["losses"], want["losses"]
     return {

@@ -1,42 +1,28 @@
 """Operations and bytes of the work the cells run, from shapes alone.
 
-Model FLOPs follow the PaLM convention: forward plus backward is 6 FLOPs
-per matmul parameter per token, plus 12 * layers * heads * head_dim *
-seq for attention (the masked half of causal attention included), with
-nothing counted for recomputation. The embedding lookup is not a matmul;
-the tied output head is. The published vocabulary is counted, not the
-padded one.
+Model FLOPs and the length of one flat update are the model family's
+(``families/<name>.py``, found by ``reference.family``); the bytes of the
+server folds are the same for every family.
 """
 from __future__ import annotations
+
+import reference
 
 F32_BYTES = 4
 
 
-def _hd(cfg: dict) -> int:
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
 def matmul_params(cfg: dict) -> int:
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    H, KV, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], _hd(cfg)
-    per_layer = d * H * hd * 2 + d * KV * hd * 2 + 3 * d * ff
-    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
+    return reference.family(cfg).matmul_params(cfg)
 
 
 def train_flops_per_token(cfg: dict, seq: int) -> float:
-    attn = 12 * cfg["num_hidden_layers"] * cfg["num_attention_heads"] * _hd(cfg) * seq
-    return 6.0 * matmul_params(cfg) + attn
+    return reference.family(cfg).train_flops_per_token(cfg, seq)
 
 
 def trained_params(cfg: dict, padded_vocab: int) -> int:
     """Every trained float of the model as the system holds it (the
     vocabulary padded as it is stored): the length of one flat update."""
-    d, ff, L = cfg["hidden_size"], cfg["intermediate_size"], cfg["num_hidden_layers"]
-    H, KV, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], _hd(cfg)
-    per_layer = 2 * d + d * H * hd * 2 + d * KV * hd * 2 + 3 * d * ff
-    if cfg.get("qk_norm"):
-        per_layer += 2 * hd
-    return padded_vocab * d + d + L * per_layer
+    return reference.family(cfg).trained_params(cfg, padded_vocab)
 
 
 def fedavg_fold_bytes(k: int, n: int) -> int:

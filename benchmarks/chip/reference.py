@@ -1,16 +1,26 @@
-"""Plain reference for the benchmark's `correct`: a dense decoder LM, its
-loss and gradients, and the federated steps the cells run, written from
-the published model descriptions in straightforward `jax.numpy`.
+"""Plain reference for the benchmark's `correct`: the federated steps the
+cells run (local SGD, the FedAvg round, the fused AdamW step, the FedAST
+FedAdam flush), over a model family's loss, written in straightforward
+`jax.numpy`.
 
-It imports nothing of the system under test. Weights come from
-``make_weights`` (made here from the seed, in the layout the system
+A model family is a module of its own, ``families/<name>.py``, found by
+``family(cfg)`` from the configuration file's ``family`` key (``dense``
+where it has none): it gives the weights' shapes and scales, the plain
+forward pass and loss, the system's ``ModelConfig`` and the counts (see
+``families/dense.py``). A new architecture arrives as a new family file.
+
+It imports nothing of the system under test (a family's
+``model_config``, which only ``cell.py`` calls, imports the system's
+``ModelConfig`` when called). Weights come from
+``make_weights_fn`` (made here from the seed, in the layout the system
 trains), and batches are the token rows the timed path was fed.
 
 Precision: float32 with `highest` matmul precision for the reference,
 or `bfloat16` throughout (params, activations and updates) for the
 control that a correct run must be told apart from.
 
-Departures from the published models, each one the system's own:
+Departures from the published models, each one the system's own and
+shared by every family through ``weighted_nll``:
 - labels are the input tokens themselves (no shift): position i is
   scored on the token at position i;
 - the vocabulary rows are padded to a multiple of 256 and the padding
@@ -21,14 +31,18 @@ Departures from the published models, each one the system's own:
 from __future__ import annotations
 
 import functools
+import importlib.util
 import json
 import math
+import re
 from contextlib import nullcontext
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+FAMILIES = Path(__file__).resolve().parent / "families"
 F32 = jnp.float32
 VOCAB_PAD = 256
 
@@ -37,47 +51,38 @@ def padded_vocab(vocab: int) -> int:
     return -(-vocab // VOCAB_PAD) * VOCAB_PAD
 
 
-def head_dim(cfg: dict) -> int:
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+# ------------------------------------------------------------------ families
+
+
+def family(cfg: dict):
+    """The model family module of a configuration: ``families/<name>.py``
+    for the file's ``family`` key, ``dense`` where it has none. Raises
+    LookupError, naming the families there, for one that is not."""
+    return _family(cfg.get("family", "dense"))
+
+
+@functools.lru_cache(maxsize=None)
+def _family(name: str):
+    path = FAMILIES / f"{name}.py"
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", name) or not path.is_file():
+        have = sorted(f.stem for f in FAMILIES.glob("*.py") if not f.stem.startswith("_"))
+        raise LookupError(f"no model family {name!r} in {FAMILIES.name}/; have {have}")
+    spec = importlib.util.spec_from_file_location(f"family_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 # ------------------------------------------------------------------ weights
 
 
-def weight_shapes(cfg: dict) -> dict:
-    """Shapes of the trained parameter pytree, layer-stacked on axis 0."""
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    H, KV, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
-    ff, V = cfg["intermediate_size"], padded_vocab(cfg["vocab_size"])
-    attn = {"wq": (L, d, H * hd), "wk": (L, d, KV * hd), "wv": (L, d, KV * hd),
-            "wo": (L, H * hd, d)}
-    if cfg.get("qk_norm"):
-        attn["q_norm"] = (L, hd)
-        attn["k_norm"] = (L, hd)
-    return {"emb": {"tok": (V, d)}, "final_norm": (d,),
-            "dense_layers": {"ln1": (L, d), "ln2": (L, d), "attn": attn,
-                             "ffn": {"gate": (L, d, ff), "up": (L, d, ff),
-                                     "down": (L, ff, d)}}}
-
-
-def _std(path: str, cfg: dict) -> float:
-    d = cfg["hidden_size"]
-    if path.endswith("tok"):
-        return 0.02
-    if path.endswith("wo"):
-        return (cfg["num_attention_heads"] * head_dim(cfg)) ** -0.5
-    if path.endswith("down"):
-        return cfg["intermediate_size"] ** -0.5
-    return d ** -0.5
-
-
 def make_weights_fn(cfg: dict):
     """A jitted ``seed_words -> params`` that makes every weight on the
-    device in one call: norms are ones, matrices are normal with the
-    usual fan-in scale. ``seed_words`` is a uint32[2] from ``seed_key``."""
-    shapes = weight_shapes(cfg)
+    device in one call: the family's gains are ones, matrices are normal
+    with its fan-in scale. ``seed_words`` is a uint32[2] from ``seed_key``."""
+    fam = family(cfg)
     flat, treedef = jax.tree.flatten_with_path(
-        shapes, is_leaf=lambda x: isinstance(x, tuple))
+        fam.weight_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
     paths = [jax.tree_util.keystr(p) for p, _ in flat]
 
     @jax.jit
@@ -86,10 +91,10 @@ def make_weights_fn(cfg: dict):
         keys = jax.random.split(key, len(flat))
         out = []
         for k, path, (p, shape) in zip(keys, paths, flat):
-            if p[-1].key in ("ln1", "ln2", "final_norm", "q_norm", "k_norm"):
+            if p[-1].key in fam.GAINS:
                 out.append(jnp.ones(shape, F32))
             else:
-                out.append(_std(path, cfg) * jax.random.normal(k, shape, F32))
+                out.append(fam.init_std(path, cfg) * jax.random.normal(k, shape, F32))
         return jax.tree.unflatten(treedef, out)
 
     return make
@@ -101,7 +106,7 @@ def seed_key(seed: int) -> np.ndarray:
     return np.array([s >> 32, s & 0xFFFFFFFF], np.uint32)
 
 
-# ------------------------------------------------------------------ model
+# ------------------------------------------------------------------ shared layers
 
 
 def _rms(x, scale, eps):
@@ -121,39 +126,9 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
 
 
-def _layer(cfg, x, lp):
-    B, S, d = x.shape
-    H, KV, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
-    eps = cfg["rms_norm_eps"]
-    a = lp["attn"]
-    h = _rms(x, lp["ln1"], eps)
-    q = (h @ a["wq"]).reshape(B, S, H, hd)
-    k = (h @ a["wk"]).reshape(B, S, KV, hd)
-    v = (h @ a["wv"]).reshape(B, S, KV, hd)
-    if cfg.get("qk_norm"):
-        q = _rms(q, a["q_norm"], eps)
-        k = _rms(k, a["k_norm"], eps)
-    q, k = _rope(q, cfg["rope_theta"]), _rope(k, cfg["rope_theta"])
-    # query head h reads key/value head h // (H / KV)
-    k = jnp.repeat(k, H // KV, axis=2)
-    v = jnp.repeat(v, H // KV, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(F32) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1).astype(x.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, S, H * hd)
-    x = x + o @ a["wo"]
-    f = lp["ffn"]
-    h = _rms(x, lp["ln2"], eps)
-    return x + (jax.nn.silu(h @ f["gate"]) * (h @ f["up"])) @ f["down"]
-
-
-def loss_fn(params, cfg, tokens, row_w=None):
-    """Weighted next-position cross entropy of token rows (B, S)."""
-    x = params["emb"]["tok"][tokens]
-    x, _ = jax.lax.scan(lambda c, lp: (_layer(cfg, c, lp), None), x,
-                        params["dense_layers"])
-    x = _rms(x, params["final_norm"], cfg["rms_norm_eps"])
-    logits = (x @ params["emb"]["tok"].T).astype(F32)
+def weighted_nll(logits, tokens, row_w=None):
+    """The system's loss from float32 logits (B, S, V): each position
+    scored on its own token, the mean weighted by each row's weight."""
     nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
         logits, tokens[..., None], -1)[..., 0]
     w = jnp.ones(tokens.shape[0], F32) if row_w is None else row_w.astype(F32)
@@ -165,7 +140,8 @@ def loss_fn(params, cfg, tokens, row_w=None):
 
 
 class Reference:
-    """The federated steps of the cells, at one precision.
+    """The federated steps of the cells over the family's loss, at one
+    precision.
 
     ``dtype`` float32 runs at `highest` matmul precision; bfloat16 casts
     params, activations and updates to bfloat16 (the control)."""
@@ -173,6 +149,7 @@ class Reference:
     def __init__(self, cfg: dict, dtype=F32):
         self.cfg = cfg
         self.dtype = jnp.dtype(dtype)
+        loss_fn = family(cfg).loss_fn
         self._vg = jax.jit(jax.value_and_grad(
             lambda p, t, w: loss_fn(p, cfg, t, w)))
 

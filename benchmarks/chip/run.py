@@ -43,7 +43,10 @@ class Refused(SystemExit):
 
 def load_cell(workload: str, root: Path = ROOT):
     """The manifest, the cell's entry, and its configuration and traffic
-    files, found by name."""
+    files, found by name; refused where the configuration's model family
+    has no module."""
+    import reference
+
     here = root / "benchmarks" / "chip"
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -53,6 +56,10 @@ def load_cell(workload: str, root: Path = ROOT):
     config = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     cfg = json.loads((root / config["file"]).read_text())
     tr = json.loads((here / "traffic" / f"{cell['traffic']}.json").read_text())
+    try:
+        reference.family(cfg)
+    except LookupError as e:
+        raise Refused(str(e)) from None
     return manifest, cell, cfg, tr
 
 

@@ -20,14 +20,13 @@ HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
 
 import counts  # noqa: E402
+import reference  # noqa: E402
 import run  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 WORKLOADS = [w["name"] for w in MANIFEST["workloads"]]
-TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-        "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 512}
 
 
 # ------------------------------------------------------------ manifest
@@ -172,7 +171,7 @@ def tiny(workload):
     cfg, tr = cell_files(workload)
     manifest = dict(MANIFEST, workloads=[{"name": workload, "chips": 1}])
     entry = manifest["workloads"][0]
-    cfg = dict(cfg, **TINY)
+    cfg = dict(cfg, **reference.family(cfg).TINY)
     if cfg.get("head_dim"):
         cfg["head_dim"] = 16
     tr = dict(tr, seq=32, shards_per_client=min(tr["shards_per_client"], 64))
