@@ -27,6 +27,13 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    # yarn rope scaling (deepseek-v2): factor 0 = plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     sliding_window: int = 0   # 0 = full attention; >0 = window size (decode)
 
     # MLA (deepseek)
@@ -45,8 +52,12 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0       # deepseek: layer 0 is dense
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25     # 0 = drop-free (sorted grouped matmul)
     norm_topk: bool = True
+    experts_held: Tuple[int, ...] = ()  # (first, end) of the routed experts
+                                      # this chip holds; () = all of them
+    aux_loss: str = "switch"          # switch | seq (deepseek's per-sequence)
+    aux_weight: float = 0.01
     moe_groups: int = 1               # dispatch groups (= dp degree at launch)
     pad_experts_to: int = 0           # pad E for expert-parallel sharding
                                       # (dummy experts masked at the router)
@@ -54,6 +65,10 @@ class ModelConfig:
     @property
     def padded_experts(self) -> int:
         return max(self.n_experts, self.pad_experts_to)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        return tuple(self.experts_held) or (0, self.padded_experts)
 
     # SSM (mamba2 / SSD)
     ssm_state: int = 0

@@ -198,16 +198,16 @@ def build_task(arch: str, preset: str, seq: int, batch: int, tau: int = 1,
     # is reproducible across processes
     params = api.init_params(
         jax.random.PRNGKey(zlib.crc32(arch.encode()) % 2**31), cfg)
-    opt_state = server_opt().init(params)
-
     if tau <= 1:
+        opt_state = server_opt().init(params)
         train_step, opt_local_fn = arch_fused_step(api, cfg)
     else:
         # TRUE FedAvg: each cohort row runs tau local SGD steps from the
         # global params; execution AND Pallas-kernel aggregation dispatch
         # through the ExecutionBackend API (the engine calls run_cohort
-        # on "local_fn" below, then backend.aggregate).
-        train_step, opt_local_fn = None, None
+        # on "local_fn" below, then backend.aggregate). No server
+        # optimizer, so no moments held on the device.
+        opt_state, train_step, opt_local_fn = None, None, None
 
     return {"cfg": cfg, "api": api, "params": params, "opt": opt_state,
             "step": train_step, "tau": tau,

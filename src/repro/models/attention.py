@@ -19,7 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dtype_of, normal, rms_norm
+from repro.models.layers import (apply_rope, dtype_of, normal, rms_norm,
+                                 yarn_mscale, yarn_of)
 
 Q_CHUNK = 512
 
@@ -279,7 +280,7 @@ def _mla_q(p, cfg, x, positions):
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     q = (x @ p["wq"]).reshape(B, S, H, dn + dr)
     qn, qr = q[..., :dn], q[..., dn:]
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = apply_rope(qr, positions, cfg.rope_theta, yarn_of(cfg))
     return qn, qr
 
 
@@ -287,7 +288,7 @@ def _mla_compress(p, cfg, x, positions):
     dr, r = cfg.qk_rope_head_dim, cfg.kv_lora_rank
     kv_a = x @ p["wkv_a"]                                  # (B,S,r+dr)
     c_kv = rms_norm(kv_a[..., :r], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(kv_a[..., r:], positions, cfg.rope_theta)
+    k_rope = apply_rope(kv_a[..., r:], positions, cfg.rope_theta, yarn_of(cfg))
     return c_kv, k_rope
 
 
@@ -298,9 +299,18 @@ def _mla_expand(p, cfg, c_kv):
     return kv[..., :dn], kv[..., dn:]                      # k_nope, v
 
 
+def mla_softmax_scale(cfg):
+    """1/sqrt(q head dim), times yarn's mscale squared where yarn sets
+    ``mscale_all_dim`` (DeepSeek-V2's softmax_scale)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if yarn_of(cfg) is not None and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_sdpa(cfg, qn, qr, kn, kr, v, q_pos, k_pos, window=0):
     """MLA attention: scores = qn.kn + qr.kr (kr shared across heads)."""
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     q = jnp.concatenate([qn, qr], axis=-1)
     B, Sk = kn.shape[0], kn.shape[1]
     kr_b = jnp.broadcast_to(kr[:, :, None, :],
@@ -312,11 +322,12 @@ def _mla_sdpa(cfg, qn, qr, kn, kr, v, q_pos, k_pos, window=0):
 
 def mla_train(p, cfg, x, positions):
     B, S, _ = x.shape
-    qn, qr = _mla_q(p, cfg, x, positions)
-    c_kv, k_rope = _mla_compress(p, cfg, x, positions)
-    kn, v = _mla_expand(p, cfg, c_kv)
-    o = _mla_sdpa(cfg, qn, qr, kn, k_rope, v, positions[0], positions[0])
-    return o.reshape(B, S, -1) @ p["wo"]
+    with jax.named_scope("mla"):
+        qn, qr = _mla_q(p, cfg, x, positions)
+        c_kv, k_rope = _mla_compress(p, cfg, x, positions)
+        kn, v = _mla_expand(p, cfg, c_kv)
+        o = _mla_sdpa(cfg, qn, qr, kn, k_rope, v, positions[0], positions[0])
+        return o.reshape(B, S, -1) @ p["wo"]
 
 
 def mla_prefill(p, cfg, x, positions):
@@ -358,7 +369,7 @@ def mla_decode(p, cfg, x, pos, cache, absorb=False):
         cache["positions"], pos[None].astype(jnp.int32), (slot,))
     H, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
-    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     if not absorb:
         kn, v = _mla_expand(p, cfg, c_kv)
         o = _mla_sdpa(cfg, qn, qr, kn, k_rope, v, posv[0], cpos,

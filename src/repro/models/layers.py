@@ -6,6 +6,8 @@ per-layer init over a key axis so params arrive pre-stacked for lax.scan.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -73,18 +75,53 @@ def gelu_mlp(p, x):
 
 # ---------------------------------------------------------------- RoPE
 
-def rope_freqs(head_dim, theta):
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+def yarn_of(cfg):
+    """The config where it scales its rope by yarn, else None."""
+    return cfg if cfg.yarn_factor > 1 else None
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., S, H, hd) or (..., S, hd); positions: (..., S)."""
+def yarn_mscale(factor, mscale):
+    """Yarn's attention temperature term (DeepSeek-V2's yarn_get_mscale)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_bounds(head_dim, theta, yarn):
+    """(low, high) rotary dims of yarn's ramp: floor/ceil of the dim whose
+    wavelength turns ``beta_fast`` / ``beta_slow`` times over the original
+    context."""
+    def dim(turns):
+        return (head_dim * math.log(yarn.yarn_original_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(yarn.yarn_beta_fast)), 0),
+            min(math.ceil(dim(yarn.yarn_beta_slow)), head_dim - 1))
+
+
+def rope_freqs(head_dim, theta, yarn=None):
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+    if yarn is None:
+        return freqs
+    # yarn: the low-frequency dims (past ``high``) interpolate by the
+    # factor, the high-frequency ones (before ``low``) keep their speed
+    low, high = yarn_bounds(head_dim, theta, yarn)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    return (freqs / yarn.yarn_factor * ramp + freqs * (1 - ramp)).astype(np.float32)
+
+
+def apply_rope(x, positions, theta, yarn=None):
+    """x: (..., S, H, hd) or (..., S, hd); positions: (..., S). ``yarn``:
+    a config with yarn scaling (``yarn_of``), or None for plain rope."""
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta))            # (hd/2,)
+    freqs = jnp.asarray(rope_freqs(hd, theta, yarn))     # (hd/2,)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, hd/2)
     if x.ndim == ang.ndim + 1:                            # has a heads axis
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.yarn_factor, yarn.yarn_mscale)
+             / yarn_mscale(yarn.yarn_factor, yarn.yarn_mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

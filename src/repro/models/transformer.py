@@ -150,7 +150,7 @@ def embed_inputs(params, cfg, batch):
     return x
 
 
-def lm_loss(params, cfg, batch, moe_groups=1, aux_weight=0.01):
+def lm_loss(params, cfg, batch, moe_groups=1):
     x = embed_inputs(params, cfg, batch)
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -166,7 +166,7 @@ def lm_loss(params, cfg, batch, moe_groups=1, aux_weight=0.01):
         mask = mask * batch["client_weights"][:, None]
     loss = cross_entropy(logits, jnp.maximum(labels, 0), mask)
     if cfg.is_moe:
-        loss = loss + aux_weight * aux
+        loss = loss + cfg.aux_weight * aux
     return loss, {"aux": aux}
 
 

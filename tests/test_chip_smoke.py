@@ -71,3 +71,22 @@ def test_four_chip_path_on_four_virtual_devices():
     assert p.returncode == 0, p.stderr[-2000:]
     assert "FOUR_OK" in p.stdout
     assert "collectives ['all-reduce']" in p.stdout
+
+
+def test_sharded_round_at_sixteen_rows_matches_vmap_on_four_virtual_devices():
+    """The benchmark's four-chip FedAvg cell runs 16 cohort rows (4 a chip)
+    on ``sharded``: one tau=2 round there gives the round ``vmap`` gives,
+    within chip_smoke's sharded-vs-vmap tolerance."""
+    prog = (
+        "import chip_smoke as cs, jax\n"
+        "from repro.launch.train import build_task\n"
+        "assert jax.device_count() == 4\n"
+        "out = {b: cs.run_phase(b, cs.sync_spec('tiny', tau=2, batch=16, backend=b, rounds=1,\n"
+        "                                        partner=False)) for b in ('sharded', 'vmap')}\n"
+        "p0 = build_task(cs.SMOLLM, 'tiny', cs.SEQ, 16, tau=2)['params']\n"
+        "err = cs.worst_rel_err(out['sharded'].params[0], out['vmap'].params[0], p0)\n"
+        "print('ROUND_ERR', err)\n")
+    p = run(["-c", prog], ROOT, XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert p.returncode == 0, p.stderr[-2000:]
+    err = p.stdout.split("ROUND_ERR")[1].split()[0]
+    assert float(err) <= chip_smoke.SHARDED_RTOL

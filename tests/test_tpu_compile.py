@@ -176,3 +176,69 @@ def test_sync_step_fits_v5e_hbm(one_chip, smollm):
     mem = step.lower(params, opt, batch).compile().memory_analysis()
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert used + mem.argument_size_in_bytes < V5E_HBM_BYTES, used
+
+
+# ------------------------------------------------ DeepSeek-V2-Lite's cell
+#
+# The benchmark's deepseek-v2-lite cell: one chip's share of the model
+# (benchmarks/chip/configs/deepseek-v2-lite.json, through its family's
+# ModelConfig), 2 cohort rows x seq 2048, tau 2, vmap backend.
+
+DS_ROWS, DS_SEQ, DS_TAU = 2, 2048, 2
+
+
+@pytest.fixture(scope="module")
+def deepseek():
+    import json
+    import sys
+    from pathlib import Path
+
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(chip) not in sys.path:
+        sys.path.insert(0, str(chip))
+    import reference
+
+    cfg = json.loads((chip / "configs" / "deepseek-v2-lite.json").read_text())
+    mc = reference.family(cfg).model_config(cfg, "deepseek-v2-lite")
+    api = get_api(mc)
+    shapes = jax.eval_shape(lambda k: api.init_params(k, mc), jax.random.PRNGKey(0))
+    return mc, api, shapes
+
+
+def test_deepseek_local_update_fits_v5e_hbm_with_the_grouped_kernel(one_chip, deepseek):
+    """The vmapped local update (the backend's jit of arch_local_fn) at the
+    cell's rows and seq: under one chip's HBM, with the routed experts'
+    grouped matmuls (forward, and the backward's input and weight
+    gradients) as the TPU's grouped kernel."""
+    from repro.launch.train import arch_local_fn
+
+    mc, api, shapes = deepseek
+    params = jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype), shapes)
+    toks = _sds((DS_ROWS, 1, DS_SEQ), one_chip, jnp.int32)
+    step = jax.jit(jax.vmap(arch_local_fn(api, mc, DS_TAU, 5e-3), in_axes=(None, 0, 0)))
+    with _as_on_tpu():
+        compiled = step.lower(params, None, {"tokens": toks, "labels": toks}).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, used
+    # 4 sparse layers x 3 projections x (forward, input and weight gradient)
+    # lie in one scanned layer: 3 forward, 3 recomputed and 6 backward
+    kernels = re.findall(r"%ragged-dot-none[\w.]* = \S+ custom-call\(", compiled.as_text())
+    assert len(kernels) == 12
+
+
+def test_deepseek_fold_fits_v5e_hbm_beside_the_params(one_chip, deepseek):
+    """The one-program Pallas fold of the cell's 2-row cohort, with the
+    global params the engine keeps resident, under one chip's HBM."""
+    from repro.api.backend import _pallas_aggregate
+
+    _, _, shapes = deepseek
+    n = int(sum(np.prod(a.shape) for a in jax.tree.leaves(shapes)))
+    assert n == 535_060_992
+    cohort = jax.tree.map(lambda a: _sds((DS_ROWS,) + a.shape, one_chip, a.dtype), shapes)
+    with _as_on_tpu():
+        compiled = _pallas_aggregate.lower(cohort, _sds((DS_ROWS,), one_chip)).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert used + 4 * n < V5E_HBM_BYTES, used
+    assert f"f32[{DS_ROWS},{n}]" in compiled.as_text()
