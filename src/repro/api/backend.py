@@ -17,15 +17,16 @@ Contract
 --------
 ``run_cohort(task_state, client_batch, rng) -> CohortResult`` executes
 ``task_state.local_fn`` — ``(params, key, *client_data) -> (update, loss)``
-for ONE client — once per entry of ``client_batch`` and stacks the results
+for ONE client — once per entry of ``client_batch`` and returns the results
 along a leading client axis. ``local_fn`` must derive all randomness from
 its ``key`` argument (the engines key by ``fold_in(round_key, client_id)``),
 so every backend computes the identical per-client result and differs only
 in *how* the cohort is scheduled:
 
-- ``serial``  — reference: one jitted call per client, Python loop.
-  Bit-exact with the pre-backend drivers (the fold_in keying makes each
-  client's update independent of its cohort neighbours).
+- ``serial``  — reference: one jitted call per client, Python loop, each
+  returning its client's row with the cohort axis already on. Bit-exact
+  with the pre-backend training loops (the fold_in keying makes each client's
+  update independent of its cohort neighbours).
 - ``vmap``    — the cohort batched into ONE jitted ``jax.vmap`` step over
   stacked per-client data, padded to the next power of two so XLA compiles
   at most log2(K)+1 cohort shapes per task.
@@ -49,6 +50,7 @@ benchmarks) reuses compilations as the pre-backend module-level jits did.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Protocol, Tuple, runtime_checkable
 
@@ -101,7 +103,9 @@ class ClientBatch:
 class CohortResult:
     """Stacked cohort output: ``updates`` mirrors ``local_fn``'s update
     pytree with a leading cohort axis; ``losses`` is the per-client local
-    loss (shape ``(n,)``)."""
+    loss (shape ``(n,)``). The serial backend makes the cohort axis inside
+    each client's compiled program, so a one-row result is that program's
+    own output; ``take_row`` donates such a result back as global state."""
 
     updates: Any
     losses: Any = None
@@ -134,11 +138,20 @@ def get_backend(backend) -> ExecutionBackend:
 _TRANSFORMS: dict = {}
 
 
-def _jit_single(local_fn):
-    got = _TRANSFORMS.get((local_fn, "single"))
+def _jit_row(local_fn):
+    """jit of ``local_fn`` whose update and loss come out as one cohort row
+    (a leading axis of 1), made inside the program: the compiler writes
+    the step's outputs in that layout, so a one-row cohort needs no copy.
+    The program keeps ``local_fn``'s name."""
+    got = _TRANSFORMS.get((local_fn, "row"))
     if got is None:
-        got = jax.jit(local_fn)
-        _TRANSFORMS[(local_fn, "single")] = got
+
+        @functools.wraps(local_fn)
+        def row_fn(*args):
+            return jax.tree.map(lambda x: x[None], local_fn(*args))
+
+        got = jax.jit(row_fn)
+        _TRANSFORMS[(local_fn, "row")] = got
     return got
 
 
@@ -182,6 +195,20 @@ def _norm_weights(weights, normalizer):
     return w / jnp.maximum(denom, 1e-12)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _take_row_jit(cohort):
+    return jax.tree.map(lambda leaf: leaf[0], cohort)
+
+
+def take_row(cohort):
+    """The one row of a one-row cohort's updates, as ONE compiled program
+    that donates the cohort: its buffers are the row's own bytes, so the
+    compiler hands them back as the result (a bitcast) rather than copying
+    the row, and ``cohort`` is deleted. Counted as a ``fold_programs``."""
+    spans.count("fold_programs")
+    return _take_row_jit(cohort)
+
+
 # ------------------------------------------------------------------ backends
 
 
@@ -197,18 +224,19 @@ class SerialBackend:
     name = "serial"
 
     def run_cohort(self, task_state, client_batch, rng=None):
-        fn = _jit_single(task_state.local_fn)
+        fn = _jit_row(task_state.local_fn)
         spans.count("cohort_rows", len(client_batch))
         spans.count("cohort_padded_rows", len(client_batch))
-        updates, losses = [], []
+        rows = []
         for i in range(len(client_batch)):
             key_i = None if client_batch.keys is None else client_batch.keys[i]
             data_i = tuple(jax.tree.map(lambda leaf: leaf[i], d) for d in client_batch.data)
-            upd, loss = fn(task_state.params, key_i, *data_i)
-            updates.append(upd)
-            losses.append(loss)
-        stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *updates)
-        return CohortResult(stacked, jnp.stack(losses))
+            rows.append(fn(task_state.params, key_i, *data_i))
+        # a one-row cohort is its row as the program wrote it: no eager op
+        # touches the update (for a fused step, the whole optimizer state)
+        updates, losses = rows[0] if len(rows) == 1 else jax.tree.map(
+            lambda *ls: jnp.concatenate(ls), *rows)
+        return CohortResult(updates, losses)
 
     def aggregate(self, stacked_updates, weights, normalizer=None):
         return _weighted_sum_jnp(stacked_updates, _norm_weights(weights, normalizer))
@@ -383,4 +411,5 @@ __all__ = [
     "VmapBackend",
     "get_backend",
     "register_backend",
+    "take_row",
 ]

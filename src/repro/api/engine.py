@@ -21,7 +21,7 @@ from typing import Any, Dict, List, Optional, Protocol
 import numpy as np
 
 from repro import spans
-from repro.api.backend import ClientBatch, CohortTask, get_backend
+from repro.api.backend import ClientBatch, CohortTask, get_backend, take_row
 from repro.api.policy import (  # noqa: F401  (re-exported legacy names)
     BID_MODELS,
     RoundContext,
@@ -452,7 +452,7 @@ class ArchSyncEngine:
     ``backend.aggregate`` (the Pallas fedavg path on compiled platforms).
     tau<=1 tasks are the fused weighted-gradient server step — dispatched
     as a degenerate single-unit cohort so every engine shares one
-    execution seam.
+    execution seam; ``take_row`` hands the row back as the new state.
     """
 
     def __init__(self, spec: ScenarioSpec, tasks, data, eligibility=None, incentive=None):
@@ -561,10 +561,13 @@ class ArchSyncEngine:
         norm = None
         if t["tau"] <= 1:
             if want_norm:
-                # displacement of the params (not opt-state) from the step
+                # displacement of the params (not opt-state) from the step,
+                # read before the fold donates the row
                 norm = spans.fetch(stacked_delta_norms(res.updates[0], t["params"])[0])
             with spans.span("fold"):
-                t["params"], t["opt"] = jax.tree.map(lambda leaf: leaf[0], res.updates)
+                # the new state is the row's own buffers; the old state is
+                # freed here, by the rebinding, as nothing donated it
+                t["params"], t["opt"] = take_row(res.updates)
             return spans.fetch(res.losses[0]), norm
         if want_norm:
             norm = spans.fetch(stacked_delta_norms(res.updates, t["params"]).mean())

@@ -169,7 +169,11 @@ def arch_fused_step(api, cfg):
     ONE fused adamw server step on the mixed p_k-weighted batch. Returns
     (train_step, opt_local_fn) — the latter wraps the step as a
     single-unit "cohort" (state = the (params, opt) pair) so the engine
-    dispatches it through the same ExecutionBackend seam. lru_cached like
+    dispatches it through the same ExecutionBackend seam. The serial
+    backend's row program puts the cohort axis on the new state inside
+    the step's own program, and the engine's ``take_row`` donates that row
+    back as the state; the step itself donates nothing, since the old
+    state is still read after it (update norms). lru_cached like
     ``arch_local_fn`` so engines for the same config share one jit."""
     opt = server_opt()
 

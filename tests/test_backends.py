@@ -129,6 +129,141 @@ def test_serial_backend_matches_reference_cohort_bitexact():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _state_fn(params, key, x):
+    """A one-client step over a (params, opt)-like state, keyed noise in."""
+    w = params["w"] * x.sum() + jax.random.normal(key, params["w"].shape)
+    return {"w": w, "opt": (params["opt"][0] - x[:2], params["opt"][1] + 1)}, jnp.square(x).mean()
+
+
+@pytest.mark.parametrize("n", [1, 3])
+def test_serial_backend_rows_are_the_local_fn_outputs(n, monkeypatch):
+    """The serial backend's cohort is bit for bit each client's jitted
+    ``local_fn`` output, stacked. A one-row cohort is the row program's
+    own output: no eager stack or concatenate runs on the state."""
+    from repro.api import ClientBatch, backend
+
+    params = {"w": jnp.arange(12.0).reshape(3, 4), "opt": (jnp.ones(2), jnp.int32(3))}
+    keys = jax.random.split(jax.random.PRNGKey(5), n)
+    x = jnp.asarray(np.random.default_rng(n).normal(size=(n, 4)), jnp.float32)
+    batch = ClientBatch(np.arange(n), keys, (x,))
+    task = CohortTask("t", params, _state_fn)
+    SerialBackend().run_cohort(task, batch)          # trace outside the count
+
+    calls, rows = [], []
+    for fname in ("stack", "concatenate"):
+        real = getattr(jnp, fname)
+        monkeypatch.setattr(jnp, fname, lambda *a, _r=real, _f=fname, **k: (
+            calls.append(_f), _r(*a, **k))[1])
+    real_row = backend._jit_row
+    monkeypatch.setattr(backend, "_jit_row", lambda fn: lambda *a: (
+        rows.append(real_row(fn)(*a)), rows[-1])[1])
+    got = SerialBackend().run_cohort(task, batch)
+
+    one = jax.jit(_state_fn)
+    want = [one(params, keys[i], x[i]) for i in range(n)]
+    want_upd = jax.tree.map(lambda *ls: np.stack(ls), *[u for u, _ in want])
+    assert jax.tree.structure(got.updates) == jax.tree.structure(want_upd)
+    for g, w in zip(jax.tree.leaves(got.updates), jax.tree.leaves(want_upd)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), w)
+    np.testing.assert_array_equal(np.asarray(got.losses), np.stack([l for _, l in want]))
+    assert len(rows) == n
+    if n == 1:
+        assert calls == []
+        for g, r in zip(jax.tree.leaves((got.updates, got.losses)), jax.tree.leaves(rows[0])):
+            assert g is r
+    else:
+        # one concatenate a leaf of the rows (3 state leaves and the loss)
+        assert calls == ["concatenate"] * 4
+
+
+def _fused_engine(rounds=3):
+    """A tiny two-task tau = 1 arch engine (the fused AdamW step as a
+    one-row cohort) whose backend records each cohort it runs."""
+    from repro.api.engine import ArchFamily
+
+    opts = {"preset": "tiny", "seq": 16, "batch": 2, "tau": 1}
+    spec = ScenarioSpec(
+        name="fused", seed=0,
+        tasks=[TaskSpec("smollm-135m", family="arch", options=opts),
+               TaskSpec("qwen3-0.6b", family="arch", options=opts)],
+        clients=ClientPopulationSpec(n_clients=4, participation=1.0),
+        runtime=RuntimeSpec(mode="sync", rounds=rounds))
+    engine = ArchFamily().sync_engine(spec)
+    seen = []
+    run_cohort = engine.backend.run_cohort
+
+    def recorded(task_state, client_batch, rng=None):
+        res = run_cohort(task_state, client_batch, rng)
+        seen.append((task_state.name, client_batch.data[0], res))
+        return res
+
+    engine.backend.run_cohort = recorded
+    return engine, seen
+
+
+def test_fused_fold_state_matches_train_step_in_order():
+    """Three tau = 1 rounds leave params and Adam state bit for bit as the
+    plain ``train_step`` leaves them on the same batches, in order."""
+    engine, seen = _fused_engine()
+    state = {a: (t["params"], t["opt"]) for a, t in engine.tasks.items()}
+    engine.run()
+    assert {name for name, _, _ in seen} == set(engine.tasks)
+    for name, data, res in seen:
+        t = engine.tasks[name]
+        loss, p, o = t["step"](*state[name], jax.tree.map(lambda v: v[0], data))
+        state[name] = (p, o)
+        np.testing.assert_array_equal(np.asarray(res.losses), np.asarray(loss)[None])
+    for name, t in engine.tasks.items():
+        want, got = jax.tree.leaves(state[name]), jax.tree.leaves((t["params"], t["opt"]))
+        assert len(want) == len(got)
+        for w, g in zip(want, got):
+            assert w.shape == g.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_fused_fold_norm_is_the_steps_displacement():
+    """``want_norm`` is read before the row is donated: the l2 norm of the
+    new params less the old, as ``stacked_delta_norms`` gives it."""
+    from repro.api.policy import stacked_delta_norms
+
+    engine, _ = _fused_engine()
+    t = engine.tasks["qwen3-0.6b"]
+    before = t["params"]
+    _, norm = engine._run_task_round("qwen3-0.6b", np.arange(2), np.random.default_rng(0),
+                                     want_norm=True)
+    want = stacked_delta_norms(jax.tree.map(lambda x: x[None], t["params"]), before)[0]
+    assert norm > 0 and norm == want
+
+
+def test_fused_fold_counts_one_fold_program_per_fold(tmp_path):
+    """Under a capture, each tau = 1 fold is one ``fold_programs``."""
+    from repro import spans
+
+    engine, seen = _fused_engine(rounds=2)
+    spans.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        engine.run()
+    assert len(seen) >= 2 and spans.counters()["fold_programs"] == len(seen)
+    spans.reset()
+
+
+def test_fused_fold_donates_the_row():
+    """The fold donates the one-row result: its state leaves are deleted,
+    its losses (read by callers after the fold) are not."""
+    probe = jnp.ones(4)
+    jax.jit(lambda x: x + 1, donate_argnums=0)(probe)
+    if not probe.is_deleted():
+        pytest.skip(f"{jax.default_backend()} does not honour buffer donation")
+    engine, seen = _fused_engine(rounds=1)
+    engine.run()
+    for _, _, res in seen:
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(res.updates))
+        assert not res.losses.is_deleted()
+    for t in engine.tasks.values():
+        assert not any(leaf.is_deleted() for leaf in jax.tree.leaves((t["params"], t["opt"])))
+
+
 def test_backend_aggregate_matches_server_aggregate():
     from repro.fed.server import aggregate
 

@@ -187,8 +187,9 @@ def test_sync_step_fits_v5e_hbm(one_chip, smollm):
 DS_ROWS, DS_SEQ, DS_TAU = 2, 2048, 2
 
 
-@pytest.fixture(scope="module")
-def deepseek():
+def _bench_model(name):
+    """A benchmark configuration (benchmarks/chip/configs/<name>.json)
+    through its family's ModelConfig: (config, api, parameter shapes)."""
     import json
     import sys
     from pathlib import Path
@@ -198,11 +199,16 @@ def deepseek():
         sys.path.insert(0, str(chip))
     import reference
 
-    cfg = json.loads((chip / "configs" / "deepseek-v2-lite.json").read_text())
-    mc = reference.family(cfg).model_config(cfg, "deepseek-v2-lite")
+    cfg = json.loads((chip / "configs" / f"{name}.json").read_text())
+    mc = reference.family(cfg).model_config(cfg, name)
     api = get_api(mc)
     shapes = jax.eval_shape(lambda k: api.init_params(k, mc), jax.random.PRNGKey(0))
     return mc, api, shapes
+
+
+@pytest.fixture(scope="module")
+def deepseek():
+    return _bench_model("deepseek-v2-lite")
 
 
 def test_deepseek_local_update_fits_v5e_hbm_with_the_grouped_kernel(one_chip, deepseek):
@@ -242,3 +248,30 @@ def test_deepseek_fold_fits_v5e_hbm_beside_the_params(one_chip, deepseek):
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert used + 4 * n < V5E_HBM_BYTES, used
     assert f"f32[{DS_ROWS},{n}]" in compiled.as_text()
+
+
+# ------------------------------------------------------ qwen3-0.6b's cell
+#
+# The benchmark's qwen3-0.6b cell: tau 1, the fused AdamW step as a
+# one-row cohort on the serial backend, its state handed back by the
+# engine's take-row.
+
+
+def test_fused_take_row_writes_no_second_state_copy(one_chip):
+    """The take-row of the cell's one-row (params, Adam state) cohort, 16
+    layers at published width in float32: the donated row's buffers come
+    back as the state, so what it writes beside them (outputs not reused
+    from the donation, and temporaries) is under 1 % of the state."""
+    from repro.api.backend import _take_row_jit
+    from repro.launch.train import server_opt
+
+    _, _, shapes = _bench_model("qwen3-0.6b")
+    n = int(sum(np.prod(a.shape) for a in jax.tree.leaves(shapes)))
+    assert n == 407_409_664
+    state = (shapes, jax.eval_shape(server_opt().init, shapes))
+    row = jax.tree.map(lambda a: _sds((1,) + a.shape, one_chip, a.dtype), state)
+    state_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(row))
+    assert 3 * 4 * n <= state_bytes < 3 * 4 * n + 64
+    mem = _take_row_jit.lower(row).compile().memory_analysis()
+    fresh = mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert fresh < 0.01 * state_bytes, (fresh, mem)
