@@ -878,9 +878,8 @@ class CheckpointSchemaRule(Rule):
     state``, helpers called with the state one class deep), and flags
     every written-but-never-read key.
 
-    Read-but-never-written keys are deliberately allowed — tolerating
-    legacy payload keys on load (``core/mmfl.py``'s pre-PR2 ``losses``)
-    is a supported compatibility idiom. Classes whose payload is built
+    Read-but-never-written keys are deliberately not flagged: a stray
+    read loses nothing at resume. Classes whose payload is built
     dynamically are skipped rather than guessed at. Covered by
     ``tests/test_analysis.py::test_ckpt01_*``.
     """
@@ -1082,10 +1081,10 @@ class UnboundedPayloadRule(Rule):
     reference, ``list()``-style materialisation, slice, or comprehension
     over it — in a ``state_dict`` return or in the ``coordinator_state``
     payload of a ``save(...)`` call. Bounded derivations (``len(acc)``,
-    scalar ``acc[-1]``) are allowed, as is reading legacy embedded
-    history on load. A literal ``"history"`` payload key is flagged
-    unconditionally: that is the legacy layout's write path, which is
-    compat-READ-only. Covered by ``tests/test_analysis.py::test_ckpt02_*``.
+    scalar ``acc[-1]``) are allowed, and the rule does not look at
+    loads. A literal ``"history"`` payload key is flagged
+    unconditionally: that is the pre-sidecar layout, which no engine
+    reads any more. Covered by ``tests/test_analysis.py::test_ckpt02_*``.
     """
 
     code = "CKPT02"

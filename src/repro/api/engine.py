@@ -87,7 +87,7 @@ class RunResult:
     dropped: int = 0
     # cost-model simulated wall clock: (T,) cumulative per-round clock
     # for sync runs (round time = max over cohort latencies), the flush
-    # event times for async runs. None only for legacy histories.
+    # event times for async runs. Every engine sets it.
     wall_clock_sim: Optional[np.ndarray] = None
     cost_dropouts: int = 0  # async jobs the cost model dropped entirely
     auction: Optional[Dict[str, Any]] = None
@@ -130,7 +130,7 @@ class RunResult:
         plus the cross-task fairness spread (max / variance) — see
         ``core.fairness.time_to_accuracy_report``. Reads the cost-model
         clock (``wall_clock_sim``; async virtual ``time`` as fallback,
-        then the round index for legacy sync histories)."""
+        then the round index, for a RunResult built without either)."""
         if self.acc is None:
             raise ValueError("this task family does not define accuracy")
         times = self.wall_clock_sim
@@ -580,11 +580,69 @@ class ArchSyncEngine:
             )
         return spans.fetch(res.losses.mean()), norm
 
+    def _resume(self, hit, rng) -> List[dict]:
+        """Restore a ``CheckpointManager.begin`` hit in place (task
+        pytrees, coordinator, data RNG, incentive, cost model) and return
+        its replayed round records, so the RunResult covers the WHOLE
+        run, not just the post-resume tail."""
+        import jax
+        import jax.numpy as jnp
+
+        state = hit.coordinator
+        # raises on aggregator/options mismatch — the saved server moments
+        # would be silently reinterpreted
+        self.aggregator.load_state(state["aggregator"])
+        for a in self.names:
+            saved = hit.tasks[a]
+            self.tasks[a]["params"] = jax.tree.map(jnp.asarray, saved["params"])
+            self.tasks[a]["opt"] = jax.tree.map(jnp.asarray, saved["opt"])
+            srv = saved.get("server_state")
+            if srv is not None:
+                self._server_state[a] = jax.tree.map(jnp.asarray, srv)
+        self.coord.load_state(state["coordinator"])
+        rng.bit_generator.state = state["data_rng"]
+        if "population" in state and self.population is not None:
+            self.population.validate_config(state["population"])
+        # incentive ledger + re-auctioned eligibility, so resumed
+        # recruitment is budget- and schedule-exact
+        if self.incentive is not None and "incentive" in state:
+            self.incentive.load_state(state["incentive"])
+            if self.incentive.eligibility is not None:
+                self.coord.eligibility = self._set_eligibility(
+                    self.incentive.eligibility)
+        self.cost_model.load_state(state["cost_model"])
+        return [rec for rec in hit.history if rec["kind"] == "round"]
+
+    def _save(self, ckpt, step: int, rng) -> None:
+        """One checkpoint step: the model and optimizer pytrees plus the
+        bounded JSON control state. The round curves are not in it: they
+        live in the sidecar, so the payload is O(1) in run length."""
+        task_state = {}
+        for a in self.names:
+            task_state[a] = {
+                "params": self.tasks[a]["params"],
+                "opt": self.tasks[a]["opt"],
+            }
+            # optimizer moments of a stateful aggregator ride with the
+            # model pytrees; omitted for stateless rules
+            if self._server_state[a] is not None:
+                task_state[a]["server_state"] = self._server_state[a]
+        coord_payload = {
+            "coordinator": self.coord.state_dict(),
+            "data_rng": rng.bit_generator.state,
+            "aggregator": self.aggregator.state_dict(),
+            "cost_model": self.cost_model.state_dict(),
+        }
+        if self.population is not None:
+            coord_payload["population"] = self.population.config_record()
+        if self.incentive is not None:
+            coord_payload["incentive"] = self.incentive.state_dict()
+        ckpt.save(step, task_state, coordinator_state=coord_payload,
+                  engine_kind="sync")
+
     def run(self, verbose: bool = False) -> RunResult:
         spec, rt = self.spec, self.spec.runtime
         rng = np.random.default_rng(spec.seed)
-        loss_hist, count_hist, alloc_hist, acc_hist = [], [], [], []
-        clock_hist: List[float] = []
         # the cost model samples from its OWN stream (seed + 3), sized
         # by the per-task parameter counts (FLOP scaling input)
         import jax as _jax
@@ -596,6 +654,9 @@ class ArchSyncEngine:
                                   _jax.tree.leaves(self.tasks[a]["params"])))
                         for a in self.names])
 
+        # one record per round: the sidecar's round record, kept in memory
+        # for the RunResult
+        rounds: List[dict] = []
         ckpt, start_round = None, 0
         if rt.checkpoint_dir:
             from repro.checkpoint import CheckpointManager
@@ -607,90 +668,12 @@ class ArchSyncEngine:
             # stale-step clear
             hit = ckpt.begin("sync", rt.resume)
             if hit is not None:
-                step, saved, coord_state = hit.step, hit.tasks, hit.coordinator
-                import jax
-                import jax.numpy as jnp
-
-                if "aggregator" in coord_state:
-                    # raises on aggregator/options mismatch — the saved
-                    # server moments would be silently reinterpreted
-                    self.aggregator.load_state(coord_state["aggregator"])
-                for a in self.names:
-                    if a in saved:
-                        self.tasks[a]["params"] = jax.tree.map(jnp.asarray, saved[a]["params"])
-                        self.tasks[a]["opt"] = jax.tree.map(jnp.asarray, saved[a]["opt"])
-                        srv = saved[a].get("server_state")
-                        if srv is not None:
-                            self._server_state[a] = jax.tree.map(jnp.asarray, srv)
-                if "coordinator" in coord_state:
-                    self.coord.load_state(coord_state["coordinator"])
-                    rng.bit_generator.state = coord_state["data_rng"]
-                    # incentive ledger + re-auctioned eligibility, so
-                    # resumed recruitment is budget- and schedule-exact
-                    if "population" in coord_state and self.population is not None:
-                        self.population.validate_config(coord_state["population"])
-                    if self.incentive is not None and "incentive" in coord_state:
-                        self.incentive.load_state(coord_state["incentive"])
-                        if self.incentive.eligibility is not None:
-                            self.coord.eligibility = self._set_eligibility(
-                                self.incentive.eligibility)
-                    # pre-checkpoint curves, so the RunResult covers the
-                    # WHOLE run, not just the post-resume tail: replayed
-                    # from the sidecar records begin() handed back, or —
-                    # legacy embedded-history checkpoint — read from the
-                    # payload itself (and backfilled into the sidecar so
-                    # the next save commits the full new-layout history)
-                    if hit.history is not None:
-                        for rec in hit.history:
-                            if rec.get("kind") != "round":
-                                continue
-                            loss_hist.append(list(rec["loss"]))
-                            count_hist.append(list(rec["counts"]))
-                            alloc_hist.append(
-                                np.asarray(rec["alloc"], np.int64))
-                            if "acc" in rec:
-                                acc_hist.append(list(rec["acc"]))
-                            if "wall_clock" in rec:
-                                clock_hist.append(float(rec["wall_clock"]))
-                    else:
-                        hist = coord_state.get("history", {})
-                        loss_hist = [list(x) for x in hist.get("loss", [])]
-                        count_hist = [list(x) for x in hist.get("counts", [])]
-                        alloc_hist = [np.asarray(x, np.int64)
-                                      for x in hist.get("alloc", [])]
-                        acc_hist = [list(x) for x in hist.get("acc", [])]
-                        clock_hist = [float(x)
-                                      for x in hist.get("wall_clock", [])]
-                    # pre-backend checkpoints carry no accuracy curve and
-                    # pre-cost-model ones no clock; only report each when
-                    # it covers the restored rounds
-                    if len(acc_hist) != len(loss_hist):
-                        acc_hist = []
-                    if len(clock_hist) != len(loss_hist):
-                        clock_hist = []
-                    if hit.history is None:
-                        for i in range(len(loss_hist)):
-                            rec = {
-                                "kind": "round",
-                                "loss": list(loss_hist[i]),
-                                "counts": list(count_hist[i]),
-                                "alloc": np.asarray(alloc_hist[i]).tolist(),
-                            }
-                            if acc_hist:
-                                rec["acc"] = list(acc_hist[i])
-                            if clock_hist:
-                                rec["wall_clock"] = float(clock_hist[i])
-                            ckpt.append_history(rec)
-                    if "cost_model" in coord_state:
-                        self.cost_model.load_state(
-                            coord_state["cost_model"])
-                else:                      # legacy pre-PR2 payload
-                    self.coord.load_state(coord_state)
-                start_round = step
+                rounds = self._resume(hit, rng)
+                start_round = hit.step
                 if verbose:
-                    print(f"resumed from round {step}")
+                    print(f"resumed from round {hit.step}")
         want_norms = self.coord.wants_update_norms
-        clock = clock_hist[-1] if clock_hist else 0.0
+        clock = rounds[-1]["wall_clock"] if rounds else 0.0
         for r in range(start_round, rt.rounds):
             with spans.step("round", r):
                 if self.incentive is not None:
@@ -737,78 +720,42 @@ class ArchSyncEngine:
                     self.coord.report(a, loss)
                     line.append(f"{a}: {loss:.3f} ({len(ids)}c)")
                 self.coord.observe([len(alloc[a]) for a in self.names], norms)
-                loss_hist.append([self.coord.tasks[a].loss for a in self.names])
-                count_hist.append([len(alloc[a]) for a in self.names])
-                alloc_hist.append(row)
                 with spans.span("eval"):
-                    acc_hist.append([self._acc_of(a) for a in self.names])
+                    acc = [self._acc_of(a) for a in self.names]
                 clock += round_time
-                clock_hist.append(clock)
+                rounds.append({
+                    "kind": "round",
+                    "loss": [self.coord.tasks[a].loss for a in self.names],
+                    "counts": [len(alloc[a]) for a in self.names],
+                    "alloc": row.tolist(),
+                    "acc": acc,
+                    "wall_clock": float(clock),
+                })
                 if ckpt is not None:
                     # whole-run history streams into the append-only sidecar
                     # (buffered; the next save fsyncs + commits the offset)
-                    ckpt.append_history({
-                        "kind": "round",
-                        "loss": list(loss_hist[-1]),
-                        "counts": list(count_hist[-1]),
-                        "alloc": row.tolist(),
-                        "acc": list(acc_hist[-1]),
-                        "wall_clock": float(clock),
-                    })
+                    ckpt.append_history(rounds[-1])
                 if verbose:
                     print(f"round {r + 1:3d} [{time.time() - t0:5.1f}s] " + " | ".join(line))
                 if ckpt and (r + 1) % rt.checkpoint_every == 0:
-                    task_state = {}
-                    for a in self.names:
-                        task_state[a] = {
-                            "params": self.tasks[a]["params"],
-                            "opt": self.tasks[a]["opt"],
-                        }
-                        # optimizer moments of a stateful aggregator ride
-                        # with the model pytrees; omitted for stateless
-                        # rules so fedavg keeps the pre-aggregator layout
-                        if self._server_state[a] is not None:
-                            task_state[a]["server_state"] = self._server_state[a]
-                    coord_payload = {
-                        "coordinator": self.coord.state_dict(),
-                        "data_rng": rng.bit_generator.state,
-                        "aggregator": self.aggregator.state_dict(),
-                        "cost_model": self.cost_model.state_dict(),
-                    }
-                    if self.population is not None:
-                        coord_payload["population"] = \
-                            self.population.config_record()
-                    if self.incentive is not None:
-                        coord_payload["incentive"] = self.incentive.state_dict()
-                    # NOTE: no history in the step payload — the whole-run
-                    # curves live in the sidecar (O(1) checkpoint size)
-                    ckpt.save(r + 1, task_state,
-                              coordinator_state=coord_payload,
-                              engine_kind="sync")
+                    self._save(ckpt, r + 1, rng)
 
         if ckpt is not None:
             ckpt.close()
-        counts = np.array(count_hist, np.int64).reshape(-1, len(self.names))
-        # resumed runs from pre-accuracy checkpoints have a partial curve;
-        # report accuracy only when it covers every round
-        acc = None
-        if len(acc_hist) == len(loss_hist):
-            acc = np.array(acc_hist).reshape(-1, len(self.names))
-        # a resume from a pre-cost-model checkpoint leaves the clock
-        # covering only the tail: report it only when it spans every round
-        wall_clock = None
-        if len(clock_hist) == len(loss_hist):
-            wall_clock = np.asarray(clock_hist, np.float64)
+        n_tasks = len(self.names)
+        counts = np.array([rec["counts"] for rec in rounds],
+                          np.int64).reshape(-1, n_tasks)
         return RunResult(
             scenario=spec.name,
             mode="sync",
             task_names=self.names,
-            loss=np.array(loss_hist),
-            acc=acc,
+            loss=np.array([rec["loss"] for rec in rounds]),
+            acc=np.array([rec["acc"] for rec in rounds]).reshape(-1, n_tasks),
             arrivals=counts.sum(axis=0),
             alloc_counts=counts,
-            alloc=np.array(alloc_hist),
-            wall_clock_sim=wall_clock,
+            alloc=np.array([rec["alloc"] for rec in rounds]),
+            wall_clock_sim=np.array([rec["wall_clock"] for rec in rounds],
+                                    np.float64),
             spec=spec,
             params=[self.tasks[a]["params"] for a in self.names],
         )

@@ -30,10 +30,8 @@ resume it restores the newest complete step, guards the writing engine
 kind, TRUNCATES the sidecar back to the committed offset (discarding
 partial lines or whole records from a killed run), and replays the
 surviving records so the resumed run's result covers the whole history.
-Checkpoints from before the sidecar (history embedded in STEP.json)
-carry no ``history_offset``; ``begin`` returns ``history=None`` for
-them and the engines fall back to the embedded payload (read-only
-compat — see docs/CHECKPOINTS.md).
+A step whose STEP.json lacks the ``engine`` or ``history_offset`` stamp
+is refused (see docs/CHECKPOINTS.md).
 
 Crash safety is tested by fault injection: every durable-write syscall
 below routes through the module-level ``_os_write`` / ``_os_fsync`` /
@@ -174,14 +172,12 @@ def load_pytree(path: str, like=None):
 class ResumeState:
     """What ``CheckpointManager.begin`` hands a resuming engine: the
     restored step, per-task pytrees, the JSON-native coordinator payload,
-    and the replayed sidecar records up to the committed offset.
-    ``history`` is None for a legacy (pre-sidecar) checkpoint whose
-    whole-run history is embedded in ``coordinator`` instead."""
+    and the replayed sidecar records up to the committed offset."""
 
     step: int
     tasks: Dict[str, Any]
     coordinator: Dict[str, Any]
-    history: Optional[List[dict]]
+    history: List[dict]
 
 
 class CheckpointManager:
@@ -367,17 +363,6 @@ class CheckpointManager:
             tasks[name] = tree
         return step, tasks, meta.get("coordinator", {})
 
-    @staticmethod
-    def _saved_kind(meta: Dict[str, Any], coord: Dict[str, Any]) -> str:
-        """Which engine wrote this step. New steps carry an explicit
-        ``engine`` stamp; pre-stamp checkpoints are inferred from the
-        payload shape (the async engine nests everything under an
-        ``async`` key, both sync engines of that era wrote ``sync``)."""
-        kind = meta.get("engine")
-        if kind is not None:
-            return str(kind)
-        return "async" if "async" in coord else "sync"
-
     def begin(self, engine_kind: str, resume: bool,
               clear_stale: bool = True) -> Optional[ResumeState]:
         """The engines' single resume/recovery entry point: decide
@@ -391,8 +376,9 @@ class CheckpointManager:
         raises instead), truncating the sidecar back to the step's
         committed ``history_offset`` (recovery: records past the offset
         were never committed — a killed run's partial tail), and
-        replaying the committed records (``history``; None for a legacy
-        embedded-history checkpoint).
+        replaying the committed records (``history``). A step without
+        the ``engine`` and ``history_offset`` stamps every engine save
+        writes raises before anything in the directory changes.
 
         Returns ``None`` when starting fresh — after clearing any stale
         step directories and sidecar (``clear_stale``): ``_gc`` assumes
@@ -402,10 +388,17 @@ class CheckpointManager:
         Safe even under ``resume=True``: reaching the fresh path means
         ``latest_step()`` found NO complete step, so anything present is
         partial junk from a killed save."""
-        if resume and self.latest_step() is not None:
-            step, tasks, coord = self.restore()
+        step = self.latest_step() if resume else None
+        if step is not None:
             meta = self._step_meta(step)
-            saved = self._saved_kind(meta, coord)
+            for key in ("engine", "history_offset"):
+                if key not in meta:
+                    raise ValueError(
+                        f"cannot resume: checkpoint step {step} in "
+                        f"{self.dir!r} has no {key!r} stamp in its "
+                        "STEP.json; only steps written by an engine "
+                        "save (the history sidecar layout) resume")
+            saved = meta["engine"]
             if saved != engine_kind:
                 if engine_kind == "async":
                     raise ValueError(
@@ -424,20 +417,10 @@ class CheckpointManager:
                     f"{self.dir!r} was written by engine kind {saved!r}, "
                     f"not {engine_kind!r}; point this run at its own "
                     "checkpoint directory")
-            history = None
-            if "history_offset" in meta:
-                off = int(meta["history_offset"])
-                self.truncate_history(off)
-                history = self.read_history(off)
-            else:
-                # legacy embedded-history step: no offset was ever
-                # committed, so ANY sidecar content (e.g. the backfill
-                # of an earlier legacy resume that died before its
-                # first save) is uncommitted garbage — drop it before
-                # the engine backfills afresh, or a later save would
-                # commit the records twice
-                self.truncate_history(0)
-            return ResumeState(step, tasks, coord, history)
+            _, tasks, coord = self.restore(step)
+            off = int(meta["history_offset"])
+            self.truncate_history(off)
+            return ResumeState(step, tasks, coord, self.read_history(off))
         if clear_stale and (self.steps()
                             or os.path.exists(self.history_path)):
             self.clear()

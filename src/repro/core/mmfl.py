@@ -174,21 +174,20 @@ class MMFLCoordinator:
         }
 
     def load_state(self, state: Dict):
-        """Inverse of ``state_dict``. Tolerates the legacy checkpoint
-        payload ``{"losses": {task: loss}}`` (pre-PR2), which restores
-        losses but not the round/RNG stream, and pre-policy payloads
-        (no "policy" key)."""
-        if "rng_state" not in state:               # legacy format
-            for n, loss in state.get("losses", {}).items():
-                if n in self.tasks:
-                    self.report(n, loss)
-            return
+        """Inverse of ``state_dict``; refuses a payload without every
+        key ``state_dict`` writes, before changing anything."""
+        missing = [k for k in ("round", "async_rr", "obs_count",
+                               "rng_state", "policy", "tasks")
+                   if k not in state]
+        if missing:
+            raise ValueError(
+                f"coordinator payload lacks {missing}: only the layout "
+                "state_dict writes is read")
         self._round = int(state["round"])
         self._async_rr = int(state["async_rr"])
-        self._obs_count = int(state.get("obs_count", 0))
+        self._obs_count = int(state["obs_count"])
         self._rng.bit_generator.state = state["rng_state"]
-        if "policy" in state:
-            self.policy.load_state(state["policy"])
+        self.policy.load_state(state["policy"])
         for n, ts in state["tasks"].items():
             if n in self.tasks:
                 t = self.tasks[n]

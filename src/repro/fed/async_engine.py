@@ -74,9 +74,8 @@ from repro.core.allocation import AllocationStrategy
 from repro.core.mmfl import MMFLCoordinator
 from repro.fed.client import accuracy
 from repro.fed.data import FedTask
-from repro.fed.trainer import (cohort_update, fed_client_batch,
-                               fed_local_fn, init_task_model,
-                               task_round_key)
+from repro.fed.trainer import (fed_client_batch, fed_local_fn,
+                               init_task_model, task_round_key)
 
 
 @dataclass
@@ -192,10 +191,8 @@ class AsyncTask:
     Cohort execution is delegated to the pluggable ExecutionBackend
     (``api.backend``): an adapter exposes its ONE-client update rule as
     ``local_fn`` plus the stacked per-client inputs via ``client_batch``;
-    the engine never runs a private per-client loop. A legacy adapter
-    that leaves ``local_fn`` as None and overrides only ``update()``
-    (the pre-backend protocol) still works — the engine falls back to
-    ``update()`` for it, outside backend dispatch. Adapters may also
+    the engine never runs a private per-client loop, and refuses an
+    adapter without ``local_fn``. Adapters may also
     define ``accuracy(params) -> float`` — when every task does, the
     history carries an eval-accuracy curve (so ``fairness_report`` unifies
     across task families).
@@ -216,17 +213,6 @@ class AsyncTask:
         a function of (seed, version, client_ids) only, so sync and async
         drivers — and every backend — agree."""
         raise NotImplementedError
-
-    def update(self, params, seed: int, version: int, client_ids):
-        """Convenience reference cohort (leading axis len(client_ids)):
-        ``local_fn`` applied per client via the serial backend."""
-        if self.local_fn is None:
-            raise NotImplementedError(
-                "AsyncTask adapters define local_fn + client_batch "
-                "(ExecutionBackend protocol) or override update()")
-        return get_backend("serial").run_cohort(
-            CohortTask(self.name, params, self.local_fn),
-            self.client_batch(seed, version, client_ids)).updates
 
     def evaluate(self, params) -> float:
         """Prevailing f_s for Eq. 4 (lower is better; the paper uses
@@ -260,12 +246,6 @@ class FedAsyncTask(AsyncTask):
         return fed_client_batch(
             self.task, task_round_key(seed, self.task_idx, version),
             client_ids)
-
-    def update(self, params, seed: int, version: int, client_ids):
-        return cohort_update(params, task_round_key(seed, self.task_idx,
-                                                    version),
-                             self.task, client_ids, self.cfg.tau,
-                             self.cfg.lr, self.cfg.batch_size)
 
     def evaluate(self, params) -> float:
         acc = float(accuracy(params, self.task.test_x, self.task.test_y))
@@ -330,6 +310,12 @@ class AsyncMMFLEngine:
                  eligibility: Optional[np.ndarray] = None,
                  incentive=None):
         self.tasks = list(tasks)
+        for t in self.tasks:
+            if t.local_fn is None:
+                raise ValueError(
+                    f"async task {t.name!r} ({type(t).__name__}) has no "
+                    "local_fn: adapters expose their one-client update "
+                    "rule as local_fn + client_batch")
         self.cfg = cfg
         self.S = len(self.tasks)
         self.K = self.tasks[0].n_clients
@@ -542,18 +528,12 @@ class AsyncMMFLEngine:
                     group = by_version[v]
                     ids = np.array([j.client for j in group], np.int64)
                     base = self._retained[s][v][0]
-                    if task.local_fn is None:
-                        # legacy adapter (pre-backend protocol): only
-                        # update() is defined — honour it, without backend
-                        # dispatch
-                        cohort = task.update(base, cfg.seed, v, ids)
-                    else:
-                        with spans.span("assemble"):
-                            batch = task.client_batch(cfg.seed, v, ids)
-                        with spans.span("cohort"):
-                            cohort = self.backend.run_cohort(
-                                CohortTask(task.name, base, task.local_fn),
-                                batch).updates
+                    with spans.span("assemble"):
+                        batch = task.client_batch(cfg.seed, v, ids)
+                    with spans.span("cohort"):
+                        cohort = self.backend.run_cohort(
+                            CohortTask(task.name, base, task.local_fn),
+                            batch).updates
                     with spans.span("deltas"):
                         for i, j in enumerate(group):
                             deltas.append(jax.tree.map(
@@ -692,11 +672,8 @@ class AsyncMMFLEngine:
 
     @staticmethod
     def _job_from_payload(p: Sequence) -> _Job:
-        # pre-cost-model checkpoints carry 4-element payloads (no
-        # dropout flag); those jobs never drop out
-        c, s, v, dt = p[:4]
-        return _Job(int(c), int(s), int(v), float(dt),
-                    bool(p[4]) if len(p) > 4 else False)
+        c, s, v, dt, dropout = p
+        return _Job(int(c), int(s), int(v), float(dt), bool(dropout))
 
     def state_dict(self) -> Dict:
         """The BOUNDED control state of a mid-run engine, JSON-native:
@@ -713,8 +690,7 @@ class AsyncMMFLEngine:
         ``checkpoint.save_pytree`` — see ``_save_checkpoint``.
         ``load_state(state_dict(), params, history=history_records())``
         then continues event-for-event identically to an uninterrupted
-        run. Layout, offset-commit semantics, and the legacy
-        embedded-history compat path are documented in
+        run. Layout and offset-commit semantics are documented in
         docs/CHECKPOINTS.md."""
         state = {
             "processed": int(self._processed),
@@ -765,8 +741,7 @@ class AsyncMMFLEngine:
         exact stream ``_record`` would have appended, modulo the
         assign/flush interleaving — replay partitions by kind, so only
         within-kind order matters). Used to serialise an engine without
-        a CheckpointManager and to BACKFILL the sidecar after resuming a
-        legacy embedded-history checkpoint."""
+        a CheckpointManager."""
         recs: List[dict] = [{"kind": "assign", "client": int(c),
                              "task": int(s)}
                             for c, s in self._assignments]
@@ -804,19 +779,18 @@ class AsyncMMFLEngine:
                 self._hist_acc.append(np.asarray(r["acc"], np.float64))
 
     def load_state(self, state: Dict, task_params: Dict,
-                   history: Optional[Sequence[dict]] = None) -> None:
+                   history: Sequence[dict]) -> None:
         """Inverse of ``state_dict``. ``task_params`` maps task name ->
         ``{"params": pytree, "retained": {str(version): pytree}}`` as
         restored by ``CheckpointManager`` (see ``_save_checkpoint``).
         ``history`` is the replayed sidecar record stream
-        (``ResumeState.history`` / ``history_records()``); omitted for a
-        legacy checkpoint whose state embeds the history directly."""
+        (``ResumeState.history`` / ``history_records()``)."""
         self.controller.reset(self.S, self.buffer_size)
         self._processed = int(state["processed"])
         self._n_flushes = int(state["n_flushes"])
         self._seq = int(state["seq"])
         self._dropped = int(state["dropped"])
-        self._cost_dropouts = int(state.get("cost_dropouts", 0))
+        self._cost_dropouts = int(state["cost_dropouts"])
         self._version = [int(v) for v in state["version"]]
         self._metric = np.asarray(state["metric"], np.float64)
         self._acc = (None if state["acc"] is None
@@ -826,10 +800,9 @@ class AsyncMMFLEngine:
         self._buffers = [[self._job_from_payload(payload)
                           for payload in buf]
                          for buf in state["buffers"]]
-        if "aggregator" in state:
-            # raises if the checkpoint was written under a different
-            # aggregator/options (the saved moments would be garbage)
-            self.aggregator.load_state(state["aggregator"])
+        # raises if the checkpoint was written under a different
+        # aggregator/options (the saved moments would be garbage)
+        self.aggregator.load_state(state["aggregator"])
         self._params, self._retained = [], []
         self._server_state = []
         for s, task in enumerate(self.tasks):
@@ -837,9 +810,7 @@ class AsyncMMFLEngine:
             self._params.append(
                 jax.tree.map(jnp.asarray, tree["params"]))
             srv = tree.get("server_state")
-            # pre-aggregator checkpoints carry no server state: re-init
-            # (zeros) — exact for fedavg (stateless), best-effort for a
-            # stateful rule resumed from an old layout
+            # stateless rules save no server state; init gives it back
             self._server_state.append(
                 jax.tree.map(jnp.asarray, srv) if srv is not None
                 else self.aggregator.init(self._params[s]))
@@ -849,25 +820,7 @@ class AsyncMMFLEngine:
                 for v, cnt in state["retained"][s].items()})
         self._arrivals = np.asarray(state["arrivals"], np.int64)
         self._per_client = np.asarray(state["per_client"], np.int64)
-        if history is not None:
-            self._replay_history(history)
-        elif "history" in state:
-            # legacy embedded-history payload (pre-sidecar layout):
-            # read-only compat — new checkpoints never write these keys
-            hist = state["history"]
-            self._assignments = [(int(c), int(s))
-                                 for c, s in state["assignments"]]
-            self._hist_time = list(hist["time"])
-            self._hist_task = [int(x) for x in hist["task"]]
-            self._hist_metric = [np.asarray(m, np.float64)
-                                 for m in hist["metric"]]
-            self._hist_stale = list(hist["stale"])
-            self._hist_acc = [np.asarray(a, np.float64)
-                              for a in hist["acc"]]
-            self._hist_bufsz = [np.asarray(b, np.int64)
-                                for b in hist["buffer_sizes"]]
-        else:
-            self._replay_history([])
+        self._replay_history(history)
         self._buffer_sizes = np.asarray(state["buffer_sizes"], np.int64)
         self.controller.load_state(state["controller"])
         self.coord.load_state(state["coordinator"])
@@ -876,14 +829,11 @@ class AsyncMMFLEngine:
         self.coord.eligibility = self._set_eligibility(state["eligibility"])
         self.arrival.load_state(state["arrival"])
         # reset first (assignments/cursors sized to this run), then
-        # restore the checkpointed sampling state over it; pre-cost-model
-        # checkpoints carry no entry — the fresh reset is exact for
-        # "constant" (stateless), best-effort otherwise
+        # restore the checkpointed sampling state over it
         self.cost_model.reset(self.K, self.S,
                               np.random.default_rng(self.cfg.seed + 3),
                               task_sizes=self._task_sizes())
-        if "cost_model" in state:
-            self.cost_model.load_state(state["cost_model"])
+        self.cost_model.load_state(state["cost_model"])
         if self.incentive is not None and "incentive" in state:
             self.incentive.load_state(state["incentive"])
         # a directly-loaded engine (no CheckpointManager involved) must
@@ -904,8 +854,7 @@ class AsyncMMFLEngine:
                              for v, slot in self._retained[s].items()},
             }
             # server-optimizer moments ride with the model pytrees (the
-            # numpy substrate); omitted entirely for stateless rules so
-            # fedavg checkpoints keep the pre-aggregator layout
+            # numpy substrate); omitted entirely for stateless rules
             if self._server_state[s] is not None:
                 trees[task.name]["server_state"] = self._server_state[s]
         ckpt.save(self._n_flushes, trees,
@@ -934,12 +883,6 @@ class AsyncMMFLEngine:
                 self.load_state(hit.coordinator["async"], hit.tasks,
                                 history=hit.history)
                 resumed = True
-                if hit.history is None:
-                    # legacy embedded-history checkpoint: backfill the
-                    # sidecar so the NEXT save commits the full history
-                    # in the new layout (a later resume replays it all)
-                    for rec in self.history_records():
-                        ckpt.append_history(rec)
                 if verbose:
                     print(f"resumed from flush {hit.step} "
                           f"(arrival {self._processed})")

@@ -365,7 +365,7 @@ class MMFLTrainer:
                 clock = float(coord["clock"])
                 # replayed sidecar records rebuild the pre-checkpoint
                 # curves, so the History covers the WHOLE run
-                for rec in hit.history or []:
+                for rec in hit.history:
                     if rec.get("kind") != "round":
                         continue
                     acc_hist.append(np.asarray(rec["acc"], np.float64))

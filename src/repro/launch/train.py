@@ -280,13 +280,6 @@ class ArchAsyncTask:
         toks = jnp.asarray(self.data[ids] % self._cfg.vocab_size)
         return ClientBatch(ids, keys, (toks,))
 
-    def update(self, params, seed, version, client_ids):
-        from repro.api.backend import CohortTask, get_backend
-
-        return get_backend("vmap").run_cohort(
-            CohortTask(self.name, params, self.local_fn),
-            self.client_batch(seed, version, client_ids)).updates
-
     def evaluate(self, params) -> float:
         return spans.fetch(self._eval(params))
 

@@ -284,44 +284,27 @@ def test_backend_aggregate_custom_normalizer():
     np.testing.assert_allclose(np.asarray(out), 0.5, rtol=1e-6)
 
 
-def test_legacy_update_only_async_adapter_still_runs():
-    """Back-compat: a pre-backend AsyncTask that overrides only update()
-    (local_fn stays None) must still drive the engine — the flush falls
-    back to update() instead of crashing inside backend dispatch."""
+def test_async_engine_refuses_an_adapter_without_local_fn():
+    """The async engine has one task protocol (local_fn + client_batch):
+    an adapter without local_fn is refused at construction, by name,
+    instead of failing later inside backend dispatch."""
     from repro.fed import AsyncConfig, AsyncMMFLEngine, standard_tasks
     from repro.fed.async_engine import AsyncTask, FedAsyncTask
-    from repro.fed.trainer import cohort_update, task_round_key
 
     tasks = standard_tasks(["synth-mnist"], n_clients=6, seed=0,
                            n_range=(40, 60))
     cfg = AsyncConfig(total_arrivals=6, buffer_size=3, tau=2, seed=0)
 
-    class Legacy(AsyncTask):
+    class NoLocalFn(AsyncTask):
         def __init__(self):
-            self.name, self.n_clients = "legacy", 6
+            self.name, self.n_clients = "bare", 6
             self.p_k, self.work = tasks[0].p_k, 1.0
-            self._ref = FedAsyncTask(tasks[0], 0, cfg)
 
-        def init(self, seed):
-            return self._ref.init(seed)
-
-        def update(self, params, seed, version, ids):
-            return cohort_update(params, task_round_key(seed, 0, version),
-                                 tasks[0], ids, 2, 0.1, 32)
-
-        def evaluate(self, params):
-            return self._ref.evaluate(params)
-
-    modern = AsyncMMFLEngine([FedAsyncTask(tasks[0], 0, cfg)], cfg).run()
-    legacy = AsyncMMFLEngine([Legacy()], cfg).run()
-    assert len(legacy.time) == len(modern.time) > 0
-    np.testing.assert_allclose(legacy.metric, modern.metric, atol=1e-6)
-    # an adapter with neither local_fn nor update() fails with a clear
-    # message, not a jit(None) TypeError
-    bare = Legacy()
-    bare.update = AsyncTask.update.__get__(bare)
-    with pytest.raises(NotImplementedError, match="local_fn"):
-        bare.update(bare.init(0), 0, 0, np.arange(2))
+    with pytest.raises(ValueError, match=r"'bare' \(NoLocalFn\).*local_fn"):
+        AsyncMMFLEngine([FedAsyncTask(tasks[0], 0, cfg), NoLocalFn()], cfg)
+    # the protocol's own adapter is accepted and runs
+    assert len(AsyncMMFLEngine([FedAsyncTask(tasks[0], 0, cfg)],
+                               cfg).run().time) > 0
 
 
 # ------------------------------------------------------------ fedavg kernel

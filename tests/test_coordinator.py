@@ -1,5 +1,6 @@
 """MMFLCoordinator (scale-level orchestration) behaviour."""
 import numpy as np
+import pytest
 
 from repro.core.allocation import AllocationStrategy
 from repro.core.mmfl import MMFLCoordinator
@@ -114,7 +115,17 @@ def test_state_dict_roundtrip_reproduces_allocations():
             np.testing.assert_array_equal(a1[k], a2[k])
 
 
-def test_load_state_legacy_losses_payload():
+def test_load_state_refuses_a_partial_payload():
+    """load_state reads only the full state_dict layout: a payload that
+    lacks any of its keys raises before the coordinator changes."""
     c = MMFLCoordinator(["a", "b"], n_clients=4, seed=0)
-    c.load_state({"losses": {"a": 0.7, "b": 0.2}})
-    assert c.tasks["a"].loss == 0.7 and c.tasks["b"].loss == 0.2
+    c.report("a", 0.4)
+    c.next_round()
+    before = c.state_dict()
+    for key in before:
+        partial = {k: v for k, v in before.items() if k != key}
+        with pytest.raises(ValueError, match=key):
+            c.load_state(partial)
+    with pytest.raises(ValueError, match="rng_state"):
+        c.load_state({"losses": {"a": 0.7, "b": 0.2}})
+    assert c.state_dict() == before
