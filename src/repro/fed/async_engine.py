@@ -54,6 +54,7 @@ Two state-management seams close the loop for LONG runs:
 """
 from __future__ import annotations
 
+import functools
 import heapq
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -181,6 +182,84 @@ def client_speeds(profile: str, n: int, rng: np.random.Generator,
         sigma = np.log(max(spread, 1.0 + 1e-6)) / 2.0
         return rng.lognormal(mean=0.0, sigma=sigma, size=n)
     raise ValueError(f"unknown speed profile: {profile!r}")
+
+
+# ------------------------------------------------ the flush's compiled steps
+
+@functools.partial(jax.jit, static_argnums=2)
+def _first_deltas(cohort, base, k):
+    """The first version group's deltas ``cohort - base`` as rows [0, n)
+    of a new (k, ...) stack; rows [n, k) are zero until the later groups
+    are written over them."""
+    def rows(c, b):
+        d = c - b[None]
+        return jnp.concatenate(
+            [d, jnp.zeros((k - d.shape[0],) + d.shape[1:], d.dtype)])
+
+    return jax.tree.map(rows, cohort, base)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _more_deltas(stack, cohort, base, row):
+    """A later group's deltas written into the donated ``stack`` in place,
+    at rows [row, row + n)."""
+    return jax.tree.map(
+        lambda s, c, b: jax.lax.dynamic_update_slice_in_dim(
+            s, c - b[None], row, 0), stack, cohort, base)
+
+
+# (tree structure, row shapes and dtypes, k) whose variants are compiled:
+# process-wide, as the jit caches that hold them are
+_DELTAS_COMPILED: set = set()
+
+
+def write_deltas(stack, cohort, base, row: int, k: int):
+    """Write one version group's client deltas, ``cohort - base``, into
+    rows [row, row + n) of a flush's (k, ...) stacked deltas, k the jobs
+    the flush keeps, as one compiled program a group: ``stack`` is None
+    for the first group (the program makes the stack) and is donated
+    after. Called for the groups in ascending version, clients in buffer
+    order, the rows are those of the eager ``jnp.stack([c[i] - b ...])``
+    in every bit: a subtraction and a copy round nothing.
+
+    Compiled variants per task tree are keyed by (k, n) alone, never by
+    how the flush splits into groups: k of the first program (n = 1..k)
+    and k - 1 of the later one (n < k), so at most B**2 over every k up
+    to the buffer size B (7 for k = 4, 16 over k <= 4). The first call
+    at a new (tree, k) compiles all 2k - 1 of them, so a split first seen
+    later compiles nothing mid-run."""
+    leaves, tree = jax.tree.flatten(cohort)
+    key = (tree, tuple((c.shape[1:], c.dtype) for c in leaves), k)
+    if key not in _DELTAS_COMPILED:
+        _DELTAS_COMPILED.add(key)
+        _compile_delta_programs(cohort, base, k)
+    if stack is None:
+        return _first_deltas(cohort, base, k)
+    return _more_deltas(stack, cohort, base, row)
+
+
+def _compile_delta_programs(cohort, base, k: int):
+    def like(tree, n=None):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape if n is None else (n,) + a.shape[1:], a.dtype), tree)
+
+    base_s = like(base)
+    stack_s = jax.eval_shape(lambda c, b: _first_deltas(c, b, k),
+                             like(cohort, k), base_s)
+    for n in range(1, k + 1):
+        _first_deltas.lower(like(cohort, n), base_s, k).compile()
+        if n < k:
+            _more_deltas.lower(stack_s, like(cohort, n), base_s, 0).compile()
+
+
+@jax.jit
+def _server_step(params, update, lr):
+    """The server step ``params + lr * update`` as one program. The old
+    params are not donated: retained, they are the base of jobs still in
+    flight. At ``lr`` 1.0 it equals the eager step in every bit; at other
+    rates the compiler fuses the product and sum into one multiply-add,
+    which can round the last bit differently from the eager pair."""
+    return jax.tree.map(lambda p, d: p + lr * d, params, update)
 
 
 class AsyncTask:
@@ -520,10 +599,11 @@ class AsyncMMFLEngine:
             if kept:
                 # one backend cohort dispatch per distinct dispatch version
                 task = self.tasks[s]
-                deltas, weights, stale = [], [], []
+                stacked, weights, stale = None, [], []
                 by_version: Dict[int, List[_Job]] = {}
                 for j in kept:
                     by_version.setdefault(j.version, []).append(j)
+                spans.count("delta_programs")
                 for v in sorted(by_version):
                     group = by_version[v]
                     ids = np.array([j.client for j in group], np.int64)
@@ -535,18 +615,15 @@ class AsyncMMFLEngine:
                             CohortTask(task.name, base, task.local_fn),
                             batch).updates
                     with spans.span("deltas"):
-                        for i, j in enumerate(group):
-                            deltas.append(jax.tree.map(
-                                lambda c, b: c[i] - b, cohort, base))
-                            weights.append(task.p_k[j.client])
-                            stale.append(cur - v)
-                            self._release(s, v)
-                with spans.span("deltas"):
-                    stacked = jax.tree.map(
-                        lambda *leaves: jnp.stack(leaves), *deltas)
-                # release the per-client copies before the fold: at published
-                # widths each is a cohort-sized block of device memory
-                del deltas, cohort
+                        stacked = write_deltas(stacked, cohort, base,
+                                               len(weights), len(kept))
+                    for j in group:
+                        weights.append(task.p_k[j.client])
+                        stale.append(cur - v)
+                        self._release(s, v)
+                # release the last cohort before the fold: at published
+                # widths it is a cohort-sized block of device memory
+                del cohort
                 # FedAST staleness discount on the weights, normalised by the
                 # UNDISCOUNTED sum (fed.server.aggregate_stale semantics),
                 # folded by the pluggable aggregator ("fedavg" dispatches the
@@ -560,9 +637,8 @@ class AsyncMMFLEngine:
                             stacked, w, np.asarray(stale, np.float32),
                             cfg.beta, self._server_state[s],
                             normalizer=w.sum())
-                    self._params[s] = jax.tree.map(
-                        lambda p, d: p + cfg.server_lr * d, self._params[s],
-                        agg)
+                    self._params[s] = _server_step(self._params[s], agg,
+                                                   cfg.server_lr)
                 self._version[s] = cur + 1
                 with spans.span("eval"):
                     self._metric[s] = task.evaluate(self._params[s])

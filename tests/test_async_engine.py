@@ -228,3 +228,205 @@ def test_assign_completion_jit_and_eligibility():
                                      jnp.array([0.2, 0.8]),
                                      jnp.ones(2), 3.0))] += 1
     np.testing.assert_allclose(counts / counts.sum(), p, atol=0.08)
+
+
+# ------------------------------------- the flush's compiled deltas and step
+
+ROW_SHAPES = {"a": ((7,), jnp.float32), "b": ((3, 5), jnp.bfloat16),
+              "c": ((2, 3, 6), jnp.float32)}
+
+
+def _tree(rng, lead=(), shapes=ROW_SHAPES):
+    return {name: jnp.asarray(rng.standard_normal(lead + shape), dtype)
+            for name, (shape, dtype) in shapes.items()}
+
+
+def _same_bits(x, y):
+    for a, b in zip(jax.tree.leaves(x), jax.tree.leaves(y), strict=True):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+def _groups(split, seed=0, shapes=ROW_SHAPES):
+    """Version groups of a flush: per group its clients (a permutation
+    of 0..k-1 split in order), cohort rows and base."""
+    rng = np.random.default_rng(seed)
+    ids = rng.permutation(sum(split))
+    out, at = [], 0
+    for n in split:
+        out.append((ids[at:at + n], _tree(rng, (n,), shapes), _tree(rng, (), shapes)))
+        at += n
+    return out
+
+
+def _write_all(groups):
+    """The engine's loop: the rows of each group in version order, its
+    clients' weights appended in the same order."""
+    from repro.fed.async_engine import write_deltas
+
+    k = sum(len(ids) for ids, _, _ in groups)
+    stacked, weights = None, []
+    for ids, cohort, base in groups:
+        stacked = write_deltas(stacked, cohort, base, len(weights), k)
+        weights.extend(ids.tolist())
+    return stacked, weights
+
+
+def _eager_stack(groups):
+    """The eager flush: one ``c[i] - b`` tree a client, then the stack."""
+    deltas = [jax.tree.map(lambda c, b: c[i] - b, cohort, base)
+              for ids, cohort, base in groups for i in range(len(ids))]
+    return jax.tree.map(lambda *leaves: jnp.stack(leaves), *deltas)
+
+
+@pytest.mark.parametrize("split", [(4,), (3, 1), (1, 2, 1), (1, 1, 1, 1)])
+def test_write_deltas_equals_the_eager_stack(split):
+    groups = _groups(split)
+    stacked, weights = _write_all(groups)
+    _same_bits(stacked, _eager_stack(groups))
+    # row r is the delta of the client whose weight is r-th
+    where = {int(c): (cohort, base, i) for ids, cohort, base in groups
+             for i, c in enumerate(ids)}
+    for r, client in enumerate(weights):
+        cohort, base, i = where[client]
+        _same_bits(jax.tree.map(lambda s: s[r], stacked),
+                   jax.tree.map(lambda c, b: c[i] - b, cohort, base))
+
+
+def _compositions(k):
+    if k == 0:
+        return [()]
+    return [(n,) + rest for n in range(1, k + 1) for rest in _compositions(k - n)]
+
+
+class _Compiles:
+    """Backend compiles while the block runs."""
+
+    def __enter__(self):
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+        return self
+
+    def _event(self, event, duration, **_):
+        self.n += event == "/jax/core/compile/backend_compile_duration"
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._event)
+
+
+def test_write_deltas_compiles_at_most_the_square_of_the_buffer():
+    """Every split of every k <= 4 kept jobs: the first flush at a new k
+    compiles at most 2k - 1 programs, 16 over k <= 4, and the other
+    splits of that k compile none, however the flushes split."""
+    buffer = 4
+    # a tree of its own: no other test has compiled its programs
+    shapes = {name: ((s[0] + 4,) + s[1:], dtype) for name, (s, dtype) in ROW_SHAPES.items()}
+    splits = {k: [_groups(s, seed=k, shapes=shapes) for s in _compositions(k)]
+              for k in range(1, buffer + 1)}
+    assert len(splits[buffer]) == 8
+    first, rest = {}, {}
+    for k, flushes in splits.items():
+        with _Compiles() as c:
+            jax.block_until_ready(_write_all(flushes[0])[0])
+        first[k] = c.n
+        with _Compiles() as c:
+            for groups in flushes[1:]:
+                jax.block_until_ready(_write_all(groups)[0])
+        rest[k] = c.n
+    assert all(0 < n <= 2 * k - 1 for k, n in first.items()), first
+    assert sum(first.values()) <= buffer ** 2
+    assert not any(rest.values()), rest
+
+
+def test_server_step_at_rate_one_equals_the_eager_step():
+    from repro.fed.async_engine import _server_step
+
+    rng = np.random.default_rng(1)
+    params, update = _tree(rng), _tree(rng)
+    _same_bits(_server_step(params, update, 1.0),
+               jax.tree.map(lambda p, d: p + 1.0 * d, params, update))
+
+
+def _eager_write_deltas(stack, cohort, base, row, k):
+    """The pre-compiled flush's deltas, group by group: the rows so far,
+    then one eager ``c[i] - b`` a client, stacked (k rows at the last)."""
+    del k
+    rows = [jax.tree.map(lambda s: s[r], stack) for r in range(row)]
+    n = jax.tree.leaves(cohort)[0].shape[0]
+    rows += [jax.tree.map(lambda c, b: c[i] - b, cohort, base) for i in range(n)]
+    return jax.tree.map(lambda *leaves: jnp.stack(leaves), *rows)
+
+
+def _run_recorded(tasks, aggregator, eager, monkeypatch):
+    """A bimodal two-task run at server_lr 1.0 with the update-norm
+    policy; its history, final state and the norms the policy saw."""
+    from repro.api.policy import GradNormPolicy
+    from repro.fed import async_engine
+
+    seen = []
+
+    class Recorded(GradNormPolicy):
+        def observe(self, obs):
+            seen.append(np.asarray(obs.update_norms))
+            super().observe(obs)
+
+    with monkeypatch.context() as m:
+        if eager:
+            m.setattr(async_engine, "write_deltas", _eager_write_deltas)
+            m.setattr(async_engine, "_server_step", lambda p, d, lr: jax.tree.map(
+                lambda a, b: a + lr * b, p, d))
+        cfg = AsyncConfig(total_arrivals=40, buffer_size=4, tau=2, seed=0,
+                          speed_profile="bimodal", policy=Recorded(),
+                          aggregator=aggregator,
+                          aggregator_options={"fused": False} if aggregator == "fedadam" else {})
+        eng = AsyncMMFLEngine.from_fed_tasks(tasks, cfg)
+        h = eng.run()
+    return h, eng._params, eng._server_state, seen
+
+
+@pytest.mark.parametrize("aggregator", ["fedavg", "fedadam"])
+def test_compiled_flush_history_equals_the_eager_flush(two_tasks, aggregator, monkeypatch):
+    from repro.fed import async_engine
+
+    split = []
+    real = async_engine.write_deltas
+
+    def spy(stack, cohort, base, row, k):
+        split.append(row)
+        return real(stack, cohort, base, row, k)
+
+    monkeypatch.setattr(async_engine, "write_deltas", spy)
+    h, params, state, norms = _run_recorded(two_tasks, aggregator, False, monkeypatch)
+    assert any(row > 0 for row in split)        # some flush wrote a later group
+    ref, ref_params, ref_state, ref_norms = _run_recorded(
+        two_tasks, aggregator, True, monkeypatch)
+    for name in ("time", "task", "metric", "staleness_mean", "versions"):
+        assert getattr(h, name).tobytes() == getattr(ref, name).tobytes(), name
+    _same_bits(params, ref_params)
+    _same_bits(state, ref_state)
+    # the update-norm policy reads the same norms off the compiled stack
+    assert len(norms) == len(h.time)
+    for a, b in zip(norms, ref_norms, strict=True):
+        assert a.tobytes() == b.tobytes()
+
+
+def test_delta_programs_counts_each_flush_that_kept_a_job(two_tasks, tmp_path,
+                                                        monkeypatch):
+    from repro import spans
+
+    flushes = []
+    real = AsyncMMFLEngine._flush
+
+    def counted(self, s, t):
+        flushes.append(s)
+        return real(self, s, t)
+
+    monkeypatch.setattr(AsyncMMFLEngine, "_flush", counted)
+    cfg = AsyncConfig(total_arrivals=60, buffer_size=4, tau=2, seed=0,
+                      speed_profile="bimodal", speed_spread=8.0, max_staleness=0)
+    spans.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        h = AsyncMMFLEngine.from_fed_tasks(two_tasks, cfg).run()
+    assert len(flushes) > len(h.time)           # some flushes dropped every job
+    assert spans.counters()["delta_programs"] == len(h.time) > 0

@@ -87,8 +87,10 @@ def test_async_flush_spans_nest_and_counters_match(tmp_path):
     assert _inside(flushes[0], events)
     assert {e[0] for e in layers} == LAYERS | {"mmfl.deltas"}
     assert all(_inside(e, flushes) for e in layers)
-    # the start reads the first model's loss and accuracy, the flush the new one's
-    assert counted == {"host_syncs": 2 + 2, "cohort_rows": ROWS, "cohort_padded_rows": 4}
+    # the start reads the first model's loss and accuracy, the flush the new one's;
+    # the flush makes its stacked deltas by the compiled program
+    assert counted == {"host_syncs": 2 + 2, "cohort_rows": ROWS, "cohort_padded_rows": 4,
+                       "delta_programs": 1}
 
 
 @pytest.mark.parametrize("backend,padded", [("serial", ROWS), ("vmap", 4)])

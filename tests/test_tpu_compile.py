@@ -275,3 +275,43 @@ def test_fused_take_row_writes_no_second_state_copy(one_chip):
     mem = _take_row_jit.lower(row).compile().memory_analysis()
     fresh = mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert fresh < 0.01 * state_bytes, (fresh, mem)
+
+
+# ------------------------------------------- smollm-135m's async flush cell
+#
+# The benchmark's async cell: buffer K, the flush's stacked deltas made by
+# one program a version group (fed/async_engine.py write_deltas).
+
+
+@pytest.mark.parametrize("first_rows", [K, 1])
+def test_async_deltas_make_one_stack_at_smollm_width(one_chip, smollm, n_params, first_rows):
+    """The first group's program makes the flush's one (K, ...) stack of
+    deltas, and a later group's writes its rows into the donated stack in
+    place: what either writes beside that stack is under 1 % of it, and
+    arguments, outputs and temporaries fit one chip's HBM."""
+    from repro.fed.async_engine import _first_deltas, _more_deltas
+
+    shapes = smollm[2]
+    base = jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype), shapes)
+
+    def rows(n):
+        return jax.tree.map(lambda a: _sds((n,) + a.shape, one_chip, a.dtype), shapes)
+
+    stack = jax.eval_shape(lambda c, b: _first_deltas(c, b, K), rows(first_rows), base)
+    assert jax.tree.map(lambda a: a.shape, stack) == jax.tree.map(lambda a: (K,) + a.shape, shapes)
+    stack_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(stack))
+    assert stack_bytes == 4 * K * n_params
+    # the chip's tiled layouts pad the narrow leaves (norms, 192-wide k and v)
+    first = _first_deltas.lower(rows(first_rows), base, K).compile().memory_analysis()
+    assert stack_bytes <= first.output_size_in_bytes < 1.05 * stack_bytes, first
+    assert first.alias_size_in_bytes == 0 and first.temp_size_in_bytes < 0.01 * stack_bytes, first
+    mems = [first]
+    if first_rows < K:
+        more = _more_deltas.lower(stack, rows(K - first_rows), base, first_rows).compile()
+        mems.append(more.memory_analysis())
+        fresh = (mems[1].output_size_in_bytes - mems[1].alias_size_in_bytes
+                 + mems[1].temp_size_in_bytes)
+        assert fresh < 0.01 * stack_bytes, mems[1]
+    for mem in mems:
+        used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+        assert used < V5E_HBM_BYTES, used
